@@ -13,11 +13,14 @@ docs/distributed.md) on the paper analogues:
   reduction the paper headlines.
 
 The full run (`run()`, wired into `python -m benchmarks.run`) executes the
-sweep in a subprocess with XLA_FLAGS forcing 8 host devices, keeping the
-parent's single-device view intact; `smoke_record()` runs in-process at
-reduced scale on whatever devices the current process has (the tier-1 /
-CI form — under the CI distributed job the process itself is started with
-8 forced host devices).
+sweep, on the CPU backend, in a subprocess with XLA_FLAGS forcing 8 host
+devices, keeping the parent's single-device view intact.  On an
+accelerator it runs in-process over `jax.devices()`: a chip belongs to
+one process, so no JAX child is started, and with a single device the
+section is skipped with its reason printed.  `smoke_record()` runs
+in-process at reduced scale on whatever devices the current process has
+(the tier-1 / CI form — under the CI distributed job the process itself
+is started with 8 forced host devices).
 
 Timings are the sharded sweep only (the any-b preamble is a host/device
 charge shared with the single-device path and benchmarked by
@@ -126,16 +129,43 @@ def smoke_record(scales=(0.02, 0.02), iters: int = 1) -> dict:
 def run(out_path="experiments/BENCH_distributed.json", scales=(0.08, 0.06),
         device_counts=(1, 2, 4, 8), iters: int = 3,
         forced_devices: int = 8, timeout: int = 1200) -> dict:
-    """Full sweep in a subprocess with `forced_devices` forced host devices
-    (the parent process keeps its own device view); writes the artifact
-    when `out_path` is given."""
+    """Full sweep; writes the artifact when `out_path` is given.  On the
+    CPU backend it runs in a subprocess with `forced_devices` forced host
+    devices (the parent process keeps its own device view); on an
+    accelerator in-process over `jax.devices()` (module doc), returning
+    None when there is only one device."""
+    import jax
+    if jax.default_backend() != "cpu":
+        devs = jax.devices()
+        if len(devs) < 2:
+            print(f"distributed section skipped: the sharded sweep needs "
+                  f"2+ devices, this process has one "
+                  f"{devs[0].platform} device ({devs[0].device_kind})")
+            return None
+        rec = _measure_record(scales=scales, device_counts=device_counts,
+                              iters=iters)
+    else:
+        rec = _forced_host_record(scales, device_counts, iters,
+                                  forced_devices, timeout)
+    if out_path:
+        p = Path(out_path)
+        p.parent.mkdir(parents=True, exist_ok=True)
+        p.write_text(json.dumps(rec, indent=2) + "\n")
+    _print_summary(rec)
+    return rec
+
+
+def _forced_host_record(scales, device_counts, iters: int,
+                        forced_devices: int, timeout: int) -> dict:
+    """`_measure_record` in a CPU-only child with `forced_devices` forced
+    host devices."""
     payload = {"scales": list(scales), "device_counts": list(device_counts),
                "iters": iters}
     env = dict(os.environ)
     env["XLA_FLAGS"] = " ".join(
         [env.get("XLA_FLAGS", ""),
          f"--xla_force_host_platform_device_count={forced_devices}"]).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.distributed_bench", "--worker",
@@ -145,13 +175,7 @@ def run(out_path="experiments/BENCH_distributed.json", scales=(0.08, 0.06),
     if out.returncode != 0:
         raise RuntimeError(f"distributed bench worker failed:\n"
                            f"{out.stderr[-4000:]}")
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    if out_path:
-        p = Path(out_path)
-        p.parent.mkdir(parents=True, exist_ok=True)
-        p.write_text(json.dumps(rec, indent=2) + "\n")
-    _print_summary(rec)
-    return rec
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def _print_summary(rec: dict) -> None:

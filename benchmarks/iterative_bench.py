@@ -50,7 +50,7 @@ def bench_matrix(L, iters: int = 3, tol: float = 1e-8,
     """One analogue: baseline CG + the two PCG variants (module doc)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = generators.spd_from_lower(L, seed=seed)
     rng = np.random.default_rng(seed)
     x_true = rng.standard_normal(A.n_rows)

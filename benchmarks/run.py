@@ -16,9 +16,11 @@ Sections:
   refactor        — value-update fast path vs full re-tuned rebuild per
                     time step (BENCH_refactor.json)
   distributed     — sharded-engine scaling curve + steps-vs-all_gathers
-                    table (BENCH_distributed.json; full mode runs in a
-                    subprocess with 8 forced host devices, smoke runs
-                    in-process on the available devices)
+                    table (BENCH_distributed.json; full mode on the CPU
+                    runs in a subprocess with 8 forced host devices, on
+                    an accelerator in-process over its devices and is
+                    skipped with one; smoke runs in-process on the
+                    available devices)
   serving         — solve-service load sweep: micro-batched throughput
                     vs sequential, cold-start latency anatomy, hot-swap
                     guarantee (BENCH_serving.json)
@@ -292,6 +294,8 @@ def smoke(out_path=None, operator_out=None, iterative_out=None,
 
 def main() -> None:
     import os
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if os.environ.get("REPRO_STRICT_DEPRECATIONS") == "1":
         # CI gate: DeprecationWarnings issued from repro's own modules are
         # errors (the string-engine shims must not regress into internal
@@ -359,8 +363,7 @@ def main() -> None:
     from benchmarks import refactor_bench
     with traced_section("refactor", trace_dir):
         refactor_bench.run(out_path="experiments/BENCH_refactor.json")
-    print("\n== Sharded scaling curve + steps-vs-all_gathers "
-          "(8 forced host devices, subprocess) ==")
+    print("\n== Sharded scaling curve + steps-vs-all_gathers ==")
     from benchmarks import distributed_bench
     with traced_section("distributed", trace_dir):
         distributed_bench.run(out_path="experiments/BENCH_distributed.json")

@@ -16,7 +16,7 @@ against unpreconditioned CG, and the schedule shapes across strategies.
     PYTHONPATH=src python examples/pcg_ic0.py
 """
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.iterative import cg
 from repro.precond import Preconditioner

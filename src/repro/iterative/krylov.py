@@ -20,9 +20,9 @@ Driver contract
   registry's batched-RHS contract so one schedule streams all k columns.
 * Every driver is a pure JAX program built on `lax.while_loop` — it
   composes with `jax.jit`, stops early when all columns converge, and
-  returns a `SolveResult` pytree.  Run under `jax.experimental.
-  enable_x64()` for float64 iterations (the repo default elsewhere:
-  float32 device math + float64 host refinement).
+  returns a `SolveResult` pytree.  Run under `jax.enable_x64()` for
+  float64 iterations (the repo default elsewhere: float32 device math +
+  float64 host refinement).
 * Convergence: ||r||_2 <= max(tol * ||b||_2, atol) per column, residuals
   in the driver's working dtype (= b's dtype).  `gmres` iterates on the
   left-preconditioned system, so its tolerance and recorded history are

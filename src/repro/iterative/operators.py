@@ -59,8 +59,8 @@ def device_matvec(A: CSR, mesh=None, axis: str = "model"):
         return matvec
 
     import jax
-    from jax.sharding import PartitionSpec as P
-    from ..solver.distributed import require_axis, shard_map_compat
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ..solver.distributed import require_axis
     require_axis(mesh, axis)
     nshards = mesh.shape[axis]
     # pad the nnz triplet to a multiple of the axis size with inert
@@ -78,21 +78,26 @@ def device_matvec(A: CSR, mesh=None, axis: str = "model"):
         out = out.at[rows].add(prod)
         return jax.lax.psum(out, axis)
 
-    shmapped = shard_map_compat(body, mesh,
-                                (P(axis), P(axis), P(axis), P()), P())
-    # the index triplet lives on device once (it is dtype-independent);
-    # the coefficient array is staged once per RHS dtype — repeat eager
-    # matvecs then transfer nothing
-    rows_dev, cols_dev = jnp.asarray(rows_sh), jnp.asarray(cols_sh)
+    # the triplet rides in as jit arguments, each device holding only its
+    # nnz shard (placed once, never staged whole on one device)
+    spmv = jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(axis), P(axis), P(axis), P()),
+                                 out_specs=P(), check_vma=False))
+    nnz_sharding = NamedSharding(mesh, P(axis))
+    # the index triplet is dtype-independent; the coefficient array is
+    # placed once per RHS dtype — repeat eager matvecs transfer nothing.
+    # Placement may run inside a jit trace: never cache tracers
+    with jax.ensure_compile_time_eval():
+        rows_dev, cols_dev = jax.device_put((rows_sh, cols_sh), nnz_sharding)
     data_by_dtype: dict = {}
 
     def matvec(x):
         data = data_by_dtype.get(x.dtype)
         if data is None:
-            with jax.ensure_compile_time_eval():    # never cache tracers
-                data = jnp.asarray(data_sh, dtype=x.dtype)
+            with jax.ensure_compile_time_eval():
+                data = jax.device_put(data_sh.astype(x.dtype), nnz_sharding)
             data_by_dtype[x.dtype] = data
-        return shmapped(rows_dev, cols_dev, data, x)[:n_rows]
+        return spmv(rows_dev, cols_dev, data, x)[:n_rows]
 
     return matvec
 

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel body
-runs as jnp ops — bit-exact semantics, no TPU lowering); on TPU set
-REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) to compile with Mosaic.
+On the CPU backend the kernels execute in interpret mode (the kernel body
+runs as jnp ops — bit-exact semantics, no TPU lowering); on a TPU they
+compile with Mosaic.  The platform decides (`default_interpret`); pass
+interpret= to pin the mode for one call.
 """
 from __future__ import annotations
 
@@ -27,7 +28,7 @@ def sptrsv_solve(sched: LevelSchedule, c: np.ndarray,
     kernel, streaming the schedule once for all right-hand sides.  Pass a
     pre-staged DeviceSchedule as `dsched` to skip restaging on repeated
     solves.  Dispatches through the engine registry: interpret=None uses
-    the registered "pallas" engine (REPRO_PALLAS_INTERPRET default), an
+    the registered "pallas" engine (interpret mode on CPU only), an
     explicit bool pins interpret mode for this call.
     """
     ds = dsched if dsched is not None else to_device(sched)

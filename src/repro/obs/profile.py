@@ -172,8 +172,7 @@ def _profile_and_solve(host, c, *, reps, warmup, clock, mesh, axis):
     else:
         from jax.sharding import PartitionSpec as P
         from ..solver.distributed import (_gather, _padded_schedule,
-                                          _step_update, require_axis,
-                                          shard_map_compat)
+                                          _step_update, require_axis)
         require_axis(mesh, axis)
         exec_sched = _padded_schedule(host, mesh.shape[axis])
         with jax.ensure_compile_time_eval():
@@ -190,8 +189,9 @@ def _profile_and_solve(host, c, *, reps, warmup, clock, mesh, axis):
                 return _step_update(x, carry, c_pad, sg,
                                     n_carry=ds.n_carry, axis=axis,
                                     gather=gather)
-            return jax.jit(shard_map_compat(
-                body, mesh, (P(), P(), P(), step_specs), (P(), P())))
+            return jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=(P(), P(), P(), step_specs),
+                out_specs=(P(), P()), check_vma=False))
 
         # the identity-gather pass keeps each device's partial updates
         # local: same per-step FLOPs, no collective, unusable numerics —

@@ -148,11 +148,13 @@ class MicroBatcher:
     def due(self, now: float) -> list:
         """Flush every key whose oldest request's deadline is <= now, in
         deadline order.  Idempotent between enqueues: a flushed key holds
-        nothing, so calling again returns []."""
+        nothing, so calling again returns [].  Equal deadlines flush in
+        enqueue order: the oldest request's `seq` breaks the tie, so keys
+        (which have no order) are never compared."""
         ready = sorted(
-            (q[0].deadline, key) for key, q in self._queues.items()
+            (q[0].deadline, q[0].seq, key) for key, q in self._queues.items()
             if q and q[0].deadline <= now)
-        return [self._flush_key(key, now, "linger") for _, key in ready]
+        return [self._flush_key(key, now, "linger") for _, _, key in ready]
 
     def flush_all(self, now: float = float("inf")) -> list:
         """Drain every pending request regardless of deadline (service

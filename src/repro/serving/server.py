@@ -96,6 +96,8 @@ def run_workload(svc: SolveService, matrices: list, *, requests: int,
 
 
 def main(argv=None) -> int:
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--tenants", type=int, default=3)

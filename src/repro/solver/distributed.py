@@ -31,37 +31,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .levelset import DeviceSchedule
+from .levelset import host_leaves
 from .schedule import LevelSchedule, WidthGroup
 
 __all__ = ["solve_sharded", "lower_sharded", "count_all_gathers",
-           "default_mesh", "require_axis", "shard_map_compat"]
-
-# jax >= 0.7 exposes shard_map/pcast at the top level; older releases keep
-# shard_map in jax.experimental and have no pcast (check_rep=False covers
-# the same replication-tracking escape hatch)
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                                   # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
-
-# public alias: other layers (repro.iterative's sharded SpMV) build their
-# own shard_map programs and must ride the same version-compat shim
-shard_map_compat = _shard_map
-
-_pcast = getattr(jax.lax, "pcast", None)
-
-
-def _mark_varying(x, axis):
-    return _pcast(x, (axis,), to="varying") if _pcast is not None else x
+           "default_mesh", "require_axis", "group_shardings"]
 
 
 @functools.lru_cache(maxsize=8)
@@ -131,9 +107,19 @@ def _padded_schedule(sched: LevelSchedule, nshards: int) -> LevelSchedule:
         compacted=sched.compacted, build_ms=sched.build_ms)
 
 
-def _stage_padded(sched: LevelSchedule, nshards: int) -> DeviceSchedule:
-    """Pad and stage (the one host-side pass the engine memoizes)."""
-    return DeviceSchedule(_padded_schedule(sched, nshards))
+def _lane_spec(leaf, axis: str) -> P:
+    """Lanes sharded over `axis`: (S, C) leaves as P(None, axis), (S, C, D)
+    tiles as P(None, axis, None); steps and deps stay whole."""
+    return P(None, axis) if leaf.ndim == 2 else P(None, axis, None)
+
+
+def group_shardings(groups, mesh: Mesh, axis: str = "model") -> tuple:
+    """A NamedSharding per leaf of `groups` (per-group leaf tuples of a
+    padded schedule), laid out as `_lane_spec` says: what `lower_sharded`
+    places the tiles with, and what a compile for a described mesh gives
+    its argument shapes."""
+    return tuple(tuple(NamedSharding(mesh, _lane_spec(l, axis)) for l in g)
+                 for g in groups)
 
 
 def _gather(v, axis):
@@ -182,8 +168,8 @@ def _sharded_body(c_pad, groups, *, n, n_carry, axis):
     carry0 = jnp.zeros((n_carry + 2,) + tail, dtype=c_pad.dtype)
     # loop carries become device-varying after the per-step all_gather;
     # mark the (identical) initial values as varying to match
-    x0 = _mark_varying(x0, axis)
-    carry0 = _mark_varying(carry0, axis)
+    x0 = jax.lax.pcast(x0, (axis,), to="varying")
+    carry0 = jax.lax.pcast(carry0, (axis,), to="varying")
 
     def body(state, step_groups):
         x, carry = _step_update(*state, c_pad, step_groups,
@@ -192,6 +178,22 @@ def _sharded_body(c_pad, groups, *, n, n_carry, axis):
 
     (x, _), _ = jax.lax.scan(body, (x0, carry0), groups)
     return x[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "n", "n_carry"))
+def _sharded_solve(groups, c, *, mesh, axis, n, n_carry):
+    """The sharded sweep with the padded tiles as jit ARGUMENTS (placed
+    lane-sharded by `group_shardings`), so no device holds the whole
+    schedule and value-repacked schedules of one layout share the
+    executable, as with the scan engine's `_scan_jit`."""
+    specs = tuple(tuple(_lane_spec(l, axis) for l in g) for g in groups)
+    body = functools.partial(_sharded_body, n=n, n_carry=n_carry, axis=axis)
+    # x ends replicated (every device applies the same gathered updates),
+    # but the replication tracker can't prove it: check_vma=False
+    shmapped = jax.shard_map(body, mesh=mesh, in_specs=(P(), specs),
+                             out_specs=P(), check_vma=False)
+    zero = jnp.zeros((1,) + c.shape[1:], c.dtype)
+    return shmapped(jnp.concatenate([c, zero], axis=0), groups)
 
 
 def solve_sharded(sched: LevelSchedule, c: np.ndarray, mesh: Mesh,
@@ -218,36 +220,23 @@ def lower_sharded(sched: LevelSchedule, mesh: Mesh, axis: str = "model"):
     `solve_sharded`), which memoizes this lowering per schedule identity.
     """
     require_axis(mesh, axis)
-    nshards = mesh.shape[axis]
+    padded = _padded_schedule(sched, mesh.shape[axis])
+    leaves = host_leaves(padded)
     # lowering may be triggered lazily from INSIDE a jit trace (an
-    # operator first used as a traced preconditioner); the staged arrays
+    # operator first used as a traced preconditioner); the placed arrays
     # are memoized on the engine, so they must be concrete, never tracers
     with jax.ensure_compile_time_eval():
-        ds = _stage_padded(sched, nshards)
-    groups = ds.leaves()
-    # lanes sharded over their group's lane dimension; x/c replicated
-    group_specs = tuple(
-        tuple(P(None, axis) if l.ndim == 2 else P(None, axis, None)
-              for l in g) for g in groups)
-    body = functools.partial(_sharded_body, n=ds.n, n_carry=ds.n_carry,
-                             axis=axis)
-    # x ends replicated (every device applies the same gathered updates),
-    # but the replication tracker can't prove it — hence the escape hatch
-    # inside _shard_map
-    shmapped = _shard_map(body, mesh, (P(), group_specs), P())
-
-    @jax.jit
-    def run_padded(c):
-        zero = jnp.zeros((1,) + c.shape[1:], c.dtype)
-        return shmapped(jnp.concatenate([c, zero], axis=0), groups)
+        groups = jax.device_put(leaves, group_shardings(leaves, mesh, axis))
+    n, n_carry, dtype = padded.n, padded.n_carry, padded.dtype
 
     def run(c):
-        c = jnp.asarray(c, dtype=ds.dtype)
-        if c.ndim not in (1, 2) or c.shape[0] != ds.n:
+        c = jnp.asarray(c, dtype=dtype)
+        if c.ndim not in (1, 2) or c.shape[0] != n:
             raise ValueError(
-                f"right-hand side must be ({ds.n},) or ({ds.n}, k) to "
+                f"right-hand side must be ({n},) or ({n}, k) to "
                 f"match the schedule, got shape {c.shape}")
-        return run_padded(c)
+        return _sharded_solve(groups, c, mesh=mesh, axis=axis, n=n,
+                              n_carry=n_carry)
 
     return run
 
@@ -269,18 +258,12 @@ def count_all_gathers(sched: LevelSchedule, mesh: Mesh | None = None,
     per-step carry placement).  The module invariant, which
     benchmarks/tests assert, is ``families == steps``.
     """
-    from .levelset import CARRY_LEAVES, GROUP_LEAVES
     if mesh is None:
         mesh = default_mesh(axis=axis, devices=jax.devices()[:1])
     require_axis(mesh, axis)
     padded = _padded_schedule(sched, mesh.shape[axis])
-    # numpy leaves, same per-group layout as DeviceSchedule.leaves():
-    # the audit only traces, so nothing needs to live on the device
-    groups = tuple(
-        tuple(getattr(g, name) for name in GROUP_LEAVES) +
-        (tuple(getattr(g, name) for name in CARRY_LEAVES)
-         if g.carry_in is not None else ())
-        for g in padded.groups)
+    # numpy leaves: the audit only traces, so nothing lives on the device
+    groups = host_leaves(padded)
     per_step: list[int] = []
 
     def gather(v, ax):
@@ -300,7 +283,8 @@ def count_all_gathers(sched: LevelSchedule, mesh: Mesh | None = None,
 
     # groups ride in as replicated closure constants: only the collective
     # structure matters here, and it is independent of the lane sharding
-    shmapped = _shard_map(body, mesh, (P(),), P())
+    shmapped = jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                             out_specs=P(), check_vma=False)
     jax.eval_shape(shmapped,
                    jax.ShapeDtypeStruct((padded.n + 1,), padded.dtype))
     return {"steps": padded.num_steps,

@@ -34,7 +34,6 @@ objects).
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import warnings
 
@@ -62,8 +61,12 @@ def compile_source(engine, sched, staged_fn):
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode default: on unless REPRO_PALLAS_INTERPRET=0."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+    """Pallas interpret mode default, chosen by the platform: the
+    interpreter on the CPU backend only.  Elsewhere the kernel compiles
+    with Mosaic or its compile raises, and the operator's fallback chain
+    reports the downgrade (EngineFallbackWarning, `stats.fallbacks`)."""
+    import jax
+    return jax.default_backend() == "cpu"
 
 
 class Engine:
@@ -157,7 +160,7 @@ class UnrolledEngine(Engine):
 class PallasEngine(Engine):
     """Pallas TPU kernel (interpret mode on CPU): one grid step per schedule
     step, x/carry resident in VMEM.  `interpret=None` follows the
-    REPRO_PALLAS_INTERPRET env default at compile time."""
+    platform (`default_interpret`) at compile time."""
 
     supports_pallas_backend = True
     dtypes = ("float32",)

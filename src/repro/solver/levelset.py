@@ -24,8 +24,8 @@ import numpy as np
 
 from .schedule import LevelSchedule
 
-__all__ = ["DeviceSchedule", "to_device", "solve_scan", "solve_unrolled",
-           "staged_scan_fn", "staged_unrolled_fn", "solve"]
+__all__ = ["DeviceSchedule", "host_leaves", "to_device", "solve_scan",
+           "solve_unrolled", "staged_scan_fn", "staged_unrolled_fn", "solve"]
 
 # leaf order within a group (row_ids doubles as the c gather index —
 # padding lanes hit the zero slot).  Carry leaves are present only for
@@ -34,9 +34,19 @@ GROUP_LEAVES = ("row_ids", "dep_idx", "dep_coef", "dinv")
 CARRY_LEAVES = ("carry_in", "carry_out")
 
 
+def host_leaves(sched: LevelSchedule) -> tuple:
+    """The schedule's numpy leaves: a tuple of per-group leaf tuples (4
+    leaves for carry-free groups, 6 with the carry slot maps), each with
+    leading dim num_steps — the layout every engine consumes."""
+    return tuple(
+        tuple(getattr(g, name) for name in GROUP_LEAVES) +
+        (tuple(getattr(g, name) for name in CARRY_LEAVES)
+         if g.carry_in is not None else ())
+        for g in sched.groups)
+
+
 class DeviceSchedule:
-    """LevelSchedule staged as jnp arrays: a tuple of per-group leaf tuples
-    (4 leaves for carry-free groups, 6 with the carry slot maps)."""
+    """LevelSchedule staged as jnp arrays (`host_leaves` layout)."""
 
     def __init__(self, sched: LevelSchedule):
         # the host LevelSchedule rides along: engines whose lowering is a
@@ -44,11 +54,7 @@ class DeviceSchedule:
         # memoizes per schedule identity) start from it rather than from
         # the staged arrays
         self.host = sched
-        self.groups = tuple(
-            tuple(jnp.asarray(getattr(g, name)) for name in GROUP_LEAVES) +
-            (tuple(jnp.asarray(getattr(g, name)) for name in CARRY_LEAVES)
-             if g.carry_in is not None else ())
-            for g in sched.groups)
+        self.groups = jax.tree.map(jnp.asarray, host_leaves(sched))
         self.group_widths = sched.group_widths
         self.n = sched.n
         self.n_carry = sched.n_carry

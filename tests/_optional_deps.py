@@ -4,7 +4,7 @@ satellite: the tier-1 suite must run without optional deps)."""
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAS_HYPOTHESIS = True
 except ModuleNotFoundError:             # pragma: no cover - env dependent
     HAS_HYPOTHESIS = False
@@ -15,6 +15,9 @@ except ModuleNotFoundError:             # pragma: no cover - env dependent
         return deco
 
     def settings(*_args, **_kwargs):
+        return lambda fn: fn
+
+    def example(*_args, **_kwargs):
         return lambda fn: fn
 
     class _AnyStrategy:

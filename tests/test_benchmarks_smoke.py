@@ -185,6 +185,29 @@ def test_run_smoke_has_distributed_section(tmp_path):
 
 
 @pytest.mark.bench
+def test_distributed_full_run_starts_no_jax_child_off_cpu(monkeypatch,
+                                                          capsys):
+    """A chip belongs to one process: off the CPU the full distributed
+    section runs in-process, and with one device it is skipped, saying
+    why, instead of starting a forced-host-device child."""
+    import types
+
+    import jax
+    from benchmarks import distributed_bench as db
+
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+
+    def no_child(*a, **k):
+        raise AssertionError("started a JAX child process")
+
+    monkeypatch.setattr(db.subprocess, "run", no_child)
+    assert db.run(out_path=None) is None
+    assert "skipped" in capsys.readouterr().out
+
+
+@pytest.mark.bench
 def test_committed_distributed_artifact_guarantee():
     """The committed experiments/BENCH_distributed.json upholds the ISSUE 5
     acceptance criteria: the all_gather count equals the step count for

@@ -195,8 +195,44 @@ def test_operator_accepts_unregistered_engine_instance():
 def test_pallas_engine_interpret_pinning():
     eng = PallasEngine(interpret=True, name="tmp-pallas")
     assert eng.interpret is True
-    assert get_engine("pallas").interpret is None       # env-default instance
+    assert get_engine("pallas").interpret is None   # platform-default instance
     assert get_engine("pallas-interpret").interpret is True
+
+
+def test_default_interpret_follows_the_platform(monkeypatch):
+    import jax
+    assert engines_mod.default_interpret() is True      # CPU backend here
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")   # no longer read
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert engines_mod.default_interpret() is False
+
+
+def test_pallas_refusal_off_cpu_is_a_counted_fallback(monkeypatch):
+    """Off the CPU the "pallas" engine never runs the interpreter: it
+    compiles with Mosaic or is refused, and a refusal is served by scan
+    behind an EngineFallbackWarning, counted in op.stats and labelled scan
+    in the trace — never a scan solve passed off as a Pallas one.  On
+    this CPU, a non-interpret pallas_call is refused like Mosaic's."""
+    import jax
+    from repro import obs
+    from repro.core.resilience import EngineFallbackWarning
+    from repro.solver import TriangularOperator
+    L, _, b = _small_problem()
+    op = TriangularOperator.from_csr(L, tune="no_rewriting", chunk=32,
+                                     max_deps=4, cache=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tracer = obs.enable()
+    try:
+        with pytest.warns(EngineFallbackWarning, match="'pallas'"):
+            x = op.solve(b, engine="pallas", max_refine=0)
+    finally:
+        obs.disable()
+    assert np.abs(x - solve_csr_seq(L, b)).max() < 1e-4
+    assert op.stats.fallbacks == 1
+    assert op.stats.last_fallback == "pallas->scan"
+    used = [s.attrs["engine_used"] for s in tracer.spans()
+            if s.name == "operator.solve"]
+    assert used == ["scan"]
 
 
 # -- dtype capability enforcement (ISSUE 5 satellite) -------------------------

@@ -5,7 +5,7 @@ full pipeline: ic0-derived, portfolio-tuned preconditioner; absolute
 residual <= 1e-8 on a poisson2d_spd system; fewer iterations than
 unpreconditioned CG; and the same solve under jax.jit for single and
 batched right-hand sides.  Float64 iterations run inside a scoped
-`jax.experimental.enable_x64()` — possible with no global config flip
+`jax.enable_x64()` — possible with no global config flip
 because the device-native preconditioner path has no host callbacks.
 """
 import numpy as np
@@ -84,7 +84,7 @@ def test_cg_acceptance_end_to_end():
     than plain CG, jit-compatible for single and batched RHS."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = generators.poisson2d_spd(16, 16)
     rng = np.random.default_rng(0)
     xt = rng.standard_normal(A.n_rows)
@@ -119,7 +119,7 @@ def test_cg_acceptance_end_to_end():
 
 def test_cg_batched_columns_match_single():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = generators.poisson2d_spd(8, 8)
     rng = np.random.default_rng(1)
     B_host = rng.standard_normal((A.n_rows, 3))
@@ -137,7 +137,7 @@ def test_cg_batched_columns_match_single():
 
 def test_residual_history_contract():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = generators.poisson2d_spd(8, 7)
     b_host = np.random.default_rng(2).standard_normal(A.n_rows)
     with enable_x64():
@@ -164,7 +164,7 @@ def test_maxiter_cap_reports_not_converged():
 
 def test_cg_x0_warm_start():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = generators.poisson2d_spd(8, 8)
     xt = np.random.default_rng(3).standard_normal(A.n_rows)
     with enable_x64():
@@ -183,7 +183,7 @@ def test_cg_rejects_bad_shape():
 
 def test_bicgstab_nonsymmetric_with_ilu0():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = nonsymmetric()
     xt = np.random.default_rng(4).standard_normal(A.n_rows)
     P = Preconditioner.ilu0(A, tune="no_rewriting", cache=False)
@@ -199,7 +199,7 @@ def test_bicgstab_nonsymmetric_with_ilu0():
 
 def test_gmres_nonsymmetric_with_ilu0():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = nonsymmetric()
     xt = np.random.default_rng(5).standard_normal(A.n_rows)
     P = Preconditioner.ilu0(A, tune="no_rewriting", cache=False)
@@ -215,7 +215,7 @@ def test_gmres_nonsymmetric_with_ilu0():
 
 def test_gmres_restart_still_converges():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = nonsymmetric(n=80, seed=9)
     xt = np.random.default_rng(6).standard_normal(A.n_rows)
     with enable_x64():
@@ -231,7 +231,7 @@ def test_gmres_restart_still_converges():
 def test_gmres_bicgstab_jit_batched():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     A = nonsymmetric(n=90, seed=10)
     P = Preconditioner.ilu0(A, tune="no_rewriting", cache=False)
     rng = np.random.default_rng(7)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.serving import Batch, BatchKey, MicroBatcher, SolveRequest
-from tests._optional_deps import HAS_HYPOTHESIS, given, settings, st
+from tests._optional_deps import HAS_HYPOTHESIS, example, given, settings, st
 
 KA = BatchKey("patA", "v0")
 KB = BatchKey("patB", "v0")
@@ -173,6 +173,9 @@ def _drive(events, max_width, max_linger_s):
        max_width=st.integers(min_value=1, max_value=5),
        linger=st.floats(min_value=0.0, max_value=0.5,
                         allow_nan=False, allow_infinity=False))
+# two keys whose linger deadlines tie: due() must not compare BatchKeys
+@example(events=[(0, 0.0, False), (1, 0.25, False)], max_width=2,
+         linger=0.25)
 def test_batcher_invariants(events, max_width, linger):
     batches, requests = _drive(events, max_width, linger)
 

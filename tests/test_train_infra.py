@@ -105,9 +105,6 @@ def test_compression_error_feedback():
     from repro.train.compression import compressed_psum_tree, init_residuals
     import jax
     from jax.sharding import PartitionSpec as P
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:       # jax < 0.7 keeps shard_map in experimental
-        from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray(np.random.default_rng(0).standard_normal(64),
                           jnp.float32)}
@@ -116,7 +113,7 @@ def test_compression_error_feedback():
     def f(g, r):
         return compressed_psum_tree(g, r, "pod")
 
-    out, new_r = jax.jit(shard_map(
+    out, new_r = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=P()))(g, r)
     # compressed value + residual == original (error feedback identity)
     np.testing.assert_allclose(np.asarray(out["w"] + new_r["w"]),
