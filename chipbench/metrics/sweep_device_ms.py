@@ -1,0 +1,8 @@
+"""Device busy milliseconds per `solve` call, over the traced calls."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or tr["busy_s"] <= 0 or not tr["calls"]:
+        return None
+    return tr["busy_s"] / tr["calls"] * 1e3
