@@ -1,0 +1,8 @@
+"""Host milliseconds per traced refined call in the program's
+`engine.preamble` spans: the T-factor preamble of each of the call's
+sweeps (the solve and every correction), run in Python on the host."""
+from chipbench.host_spans import per_call_ms
+
+
+def read(ctx):
+    return per_call_ms(ctx, "engine.preamble")

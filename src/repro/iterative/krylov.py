@@ -112,12 +112,26 @@ class SolveResult(typing.NamedTuple):
 
 
 def _vdot(u, v):
-    return (u * v).sum(axis=0)
+    import jax
+    with jax.named_scope("krylov.reduce"):
+        return (u * v).sum(axis=0)
 
 
 def _norm(v):
+    import jax
     import jax.numpy as jnp
-    return jnp.sqrt(_vdot(v, v))
+    with jax.named_scope("krylov.reduce"):
+        return jnp.sqrt((v * v).sum(axis=0))
+
+
+def _scoped(name: str, fn):
+    """`fn` with its ops under the name scope `name` (trace-time only)."""
+    import jax
+
+    def call(v):
+        with jax.named_scope(name):
+            return fn(v)
+    return call
 
 
 def _guard(d):
@@ -129,8 +143,10 @@ def _guard(d):
 def _prepare(matvec, preconditioner, b, x0, tol, atol):
     """Shared setup: resolve operators, initial x/r, convergence target."""
     import jax.numpy as jnp
-    A = as_matvec(matvec)
-    M = as_preconditioner(preconditioner)
+    # device ops of the SpMV and of M^-1 carry these name scopes in a
+    # profile, as the reductions carry `krylov.reduce`
+    A = _scoped("krylov.matvec", as_matvec(matvec))
+    M = _scoped("krylov.precond", as_preconditioner(preconditioner))
     b = jnp.asarray(b)
     if b.ndim not in (1, 2):
         raise ValueError(f"b must be (n,) or (n, k), got shape {b.shape}")

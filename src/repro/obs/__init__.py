@@ -10,8 +10,8 @@ Four pieces, all zero-dependency and off-by-default:
 * `profile` — the per-step schedule profiler + `ProfilingEngine` wrapper
   (collective vs. compute split on the sharded path); feeds
   `CostModel.calibrate`.
-* `export`  — Chrome trace-event, JSON-lines, and Prometheus text
-  exporters plus the validators CI runs.
+* `export`  — Chrome trace-event and Prometheus text exporters plus
+  the validators CI runs.
 
 Quick trace of a solve::
 

@@ -1,6 +1,6 @@
 """Exporters + validators for traces and metrics.
 
-Three output formats (docs/observability.md shows each):
+Two output formats (docs/observability.md shows each):
 
 * **Chrome trace-event JSON** — load in `chrome://tracing` or Perfetto.
   Spans become `ph:"X"` complete events (ts/dur in µs, rebased to the
@@ -11,8 +11,6 @@ Three output formats (docs/observability.md shows each):
   a queue span parented under another thread's batch span — survive in
   `args.parent_id` only, and `validate_chrome_trace` deliberately does
   NOT require child intervals inside the parent's for that reason).
-* **JSON-lines event log** — one object per span/event/metrics-snapshot,
-  grep- and pandas-friendly.
 * **Prometheus text exposition** — every instrument of one or more
   `MetricsRegistry` sources as `<prefix>_<name>` families; histograms
   expand to cumulative `_bucket{le=...}` + `_sum`/`_count`, text
@@ -31,7 +29,7 @@ import math
 import re
 
 __all__ = ["chrome_trace", "write_chrome_trace", "validate_chrome_trace",
-           "write_jsonl", "prometheus_text", "validate_prometheus_text"]
+           "prometheus_text", "validate_prometheus_text"]
 
 
 def _json_safe(v):
@@ -141,34 +139,6 @@ def validate_chrome_trace(doc) -> list:
             problems.append(
                 f"{where} ({name}): parent_id {pid} does not resolve")
     return problems
-
-
-# ----------------------------------------------------------------------
-# JSON-lines event log
-
-def write_jsonl(path, tracer=None, registries=()) -> int:
-    """One JSON object per line: spans, orphan events, then one metrics
-    snapshot per registry. Returns the number of lines written."""
-    lines = []
-    if tracer is not None:
-        for sp in tracer.spans():
-            lines.append({
-                "type": "span", "name": sp.name, "span_id": sp.span_id,
-                "parent_id": sp.parent_id, "t_start": sp.t_start,
-                "t_end": sp.t_end, "tid": sp.tid, "attrs": _args(sp.attrs),
-                "events": [{"name": n, "t": t, "attrs": _args(a)}
-                           for n, t, a in sp.events],
-            })
-        for name, t, attrs, tid in tracer.orphan_events():
-            lines.append({"type": "event", "name": name, "t": t,
-                          "tid": tid, "attrs": _args(attrs)})
-    for reg in registries:
-        lines.append({"type": "metrics", "prefix": reg.prefix,
-                      "snapshot": reg.snapshot()})
-    with open(path, "w") as fh:
-        for obj in lines:
-            fh.write(json.dumps(obj, default=str) + "\n")
-    return len(lines)
 
 
 # ----------------------------------------------------------------------

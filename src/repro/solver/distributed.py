@@ -123,7 +123,9 @@ def group_shardings(groups, mesh: Mesh, axis: str = "model") -> tuple:
 
 
 def _gather(v, axis):
-    return jax.lax.all_gather(v, axis, tiled=True)
+    """The per-step collective, under the name scope `sptrsv.exchange`."""
+    with jax.named_scope("sptrsv.exchange"):
+        return jax.lax.all_gather(v, axis, tiled=True)
 
 
 def _step_update(x, carry, c_pad, step_groups, *, n_carry, axis,
@@ -172,8 +174,9 @@ def _sharded_body(c_pad, groups, *, n, n_carry, axis):
     carry0 = jax.lax.pcast(carry0, (axis,), to="varying")
 
     def body(state, step_groups):
-        x, carry = _step_update(*state, c_pad, step_groups,
-                                n_carry=n_carry, axis=axis)
+        with jax.named_scope("sptrsv.step"):
+            x, carry = _step_update(*state, c_pad, step_groups,
+                                    n_carry=n_carry, axis=axis)
         return (x, carry), None
 
     (x, _), _ = jax.lax.scan(body, (x0, carry0), groups)

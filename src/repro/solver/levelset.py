@@ -92,8 +92,12 @@ def _group_body(x, carry, c_pad, leaves_g):
 
 
 def _step_body(x, carry, c_pad, step_groups):
-    for leaves_g in step_groups:
-        x, carry = _group_body(x, carry, c_pad, leaves_g)
+    """One schedule step; its ops carry the name scope `sptrsv.step`, so a
+    profile tells the step's gathers, FMAs and scatters from the loop's
+    own slicing and copies."""
+    with jax.named_scope("sptrsv.step"):
+        for leaves_g in step_groups:
+            x, carry = _group_body(x, carry, c_pad, leaves_g)
     return x, carry
 
 

@@ -141,8 +141,12 @@ def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, src, row_pos,
     `TriangularOperator.device_solve_fn` (production applications) and
     `Preconditioner._measure_pair` (measured pair tuning) build on it, so
     the tuner always times exactly the computation it selects for.
-    `pre_fn`/`src`/`row_pos` are None for identity preambles.
+    `pre_fn`/`src`/`row_pos` are None for identity preambles.  The
+    preamble and the main schedule run under the name scopes
+    `sptrsv.preamble` and `sptrsv.main`, which label their device ops in
+    a profile (docs/observability.md).
     """
+    import jax
     import jax.numpy as jnp
 
     def fn(v):
@@ -151,8 +155,10 @@ def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, src, row_pos,
         if reversed_:
             c = jnp.flip(c, axis=0)
         if pre_fn is not None:
-            c = pre_fn(c[src])[row_pos]
-        x = main_fn(c)
+            with jax.named_scope("sptrsv.preamble"):
+                c = pre_fn(c[src])[row_pos]
+        with jax.named_scope("sptrsv.main"):
+            x = main_fn(c)
         if reversed_:
             x = jnp.flip(x, axis=0)
         return x.astype(out_dtype)
@@ -931,10 +937,28 @@ class TriangularOperator:
         return dt
 
     def _device_solve(self, c: np.ndarray, engine) -> np.ndarray:
-        """One schedule execution in the schedule dtype."""
+        """One schedule execution in the schedule dtype: the copy to the
+        device, the run (dispatch and device wait) and the copy back, each
+        in its own span."""
+        import jax
         import jax.numpy as jnp
-        return np.asarray(self._compiled_fn(engine)(
-            jnp.asarray(c, dtype=self._canon_dtype())))
+        fn = self._compiled_fn(engine)
+        with _obs.span("engine.put"):
+            c = jnp.asarray(c, dtype=self._canon_dtype())
+        with _obs.span("engine.run"):
+            out = jax.block_until_ready(fn(c))
+        with _obs.span("engine.get"):
+            return np.asarray(out)
+
+    def _preamble_rows(self) -> int:
+        """Rows of the T factor with entries: the rows the host preamble
+        eliminates (0 = identity preamble), counted once on the shared
+        payload."""
+        rows = self._runtime.get("preamble_rows")
+        if rows is None:
+            rows = self._runtime["preamble_rows"] = int(
+                np.count_nonzero(self._ts.T.row_nnz()))
+        return rows
 
     def _preamble_host(self):
         """(LevelSchedule|None, src, row_pos) for the T-factor preamble,
@@ -1010,7 +1034,12 @@ class TriangularOperator:
         corrections accumulate at full precision."""
         if self._reversed:
             v = v[::-1]
-        x = self._device_solve(self._ts.preamble(v), engine)
+        rows = self._preamble_rows()
+        span = _obs.span("engine.preamble", rows=rows) if rows \
+            else _obs.NULL_SPAN
+        with span:
+            c = self._ts.preamble(v)
+        x = self._device_solve(c, engine)
         if out_dtype is not None:
             x = x.astype(out_dtype)
         return x[::-1] if self._reversed else x
@@ -1236,8 +1265,11 @@ class TriangularOperator:
                 bscale = max(1.0, float(np.abs(b).max(initial=0.0)))
                 with _obs.span("operator.refine", tol=refine_tol) as rsp:
                     while True:
-                        r = b - self._L.matvec(x, transpose=self.transpose)
-                        resid = float(np.abs(r).max(initial=0.0)) / bscale
+                        with _obs.span("operator.residual"):
+                            r = b - self._L.matvec(x,
+                                                   transpose=self.transpose)
+                            resid = float(np.abs(r).max(initial=0.0)) \
+                                / bscale
                         if not np.isfinite(resid):
                             break   # poisoned pipeline: corrections would
                                     # be NaN too — the health action below

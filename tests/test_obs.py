@@ -30,8 +30,7 @@ import pytest
 from repro import obs
 from repro.obs.export import (chrome_trace, prometheus_text,
                               validate_chrome_trace,
-                              validate_prometheus_text, write_chrome_trace,
-                              write_jsonl)
+                              validate_prometheus_text, write_chrome_trace)
 from repro.obs.metrics import (DEFAULT_MS_BUCKETS, MetricsRegistry,
                                nearest_rank_percentile)
 from repro.obs.trace import NULL_SPAN, Tracer
@@ -519,20 +518,6 @@ def test_chrome_validator_flags_problems():
     assert validate_chrome_trace({"nope": 1})
 
 
-def test_jsonl_export(tmp_path):
-    tr = _sample_tracer()
-    reg = MetricsRegistry(prefix="t")
-    reg.counter("hits", "h").inc(3)
-    n = write_jsonl(tmp_path / "log.jsonl", tracer=tr, registries=[reg])
-    lines = [json.loads(l) for l in
-             (tmp_path / "log.jsonl").read_text().splitlines()]
-    assert len(lines) == n
-    kinds = {l["type"] for l in lines}
-    assert kinds == {"span", "event", "metrics"}
-    m = [l for l in lines if l["type"] == "metrics"][0]
-    assert m["snapshot"]["hits"]["series"][""] == 3
-
-
 def test_prometheus_text_round_trip():
     reg = MetricsRegistry(prefix="repro_test")
     reg.counter("hits", "total hits").inc(5, route="a")
@@ -591,6 +576,198 @@ def test_krylov_emits_residual_events():
     hist = np.asarray(res.residual_norms, dtype=float)
     for _, a in evts:
         assert hist[a["iteration"]] == pytest.approx(a["residual"])
+
+
+# ----------------------------------------------------------------------
+# host spans and device name scopes inside a sweep
+
+
+@pytest.fixture(scope="module")
+def lung_op():
+    """A transformed lung2 sweep: its T-factor preamble is not the
+    identity, so the host preamble span is opened."""
+    from repro.solver import TriangularOperator
+    L = generators.lung2_like(scale=0.03)
+    op = TriangularOperator.from_csr(L, tune="avgLevelCost", cache=False)
+    assert not op.transformed.identity_preamble
+    return L, op
+
+
+def _children(spans, parent) -> list:
+    """Names of `parent`'s child spans, in the order they were entered."""
+    return [s.name for s in sorted(spans, key=lambda s: s.span_id)
+            if s.parent_id == parent.span_id]
+
+
+SWEEP_SPANS = ["engine.preamble", "engine.put", "engine.run", "engine.get"]
+
+
+def test_raw_solve_splits_engine_solve_into_its_parts(lung_op):
+    L, op = lung_op
+    b = np.ones(L.n_rows)
+    op.solve(b, max_refine=0)                   # compile outside the trace
+    tr = obs.enable()
+    op.solve(b, max_refine=0)
+    obs.disable()
+    spans = tr.spans()
+    (solve,) = [s for s in spans if s.name == "operator.solve"]
+    (eng,) = [s for s in spans if s.name == "engine.solve"]
+    assert eng.parent_id == solve.span_id
+    assert _children(spans, eng) == SWEEP_SPANS
+    by_name = {s.name: s for s in spans}
+    rows = int(np.count_nonzero(op.transformed.T.row_nnz()))
+    assert by_name["engine.preamble"].attrs == {"rows": rows} and rows > 0
+    assert not any(s.name == "operator.residual" for s in spans)
+    # the parts lie inside the span that holds them
+    for name in SWEEP_SPANS:
+        sp = by_name[name]
+        assert eng.t_start <= sp.t_start <= sp.t_end <= eng.t_end
+
+
+def test_refined_solve_spans_one_residual_per_evaluation(lung_op):
+    L, op = lung_op
+    b = np.ones(L.n_rows)
+    op.solve(b)
+    tr = obs.enable()
+    op.solve(b)
+    obs.disable()
+    spans = tr.spans()
+    (refine,) = [s for s in spans if s.name == "operator.refine"]
+    rounds = refine.attrs["rounds"]
+    assert rounds >= 1
+    assert _children(spans, refine) == \
+        ["operator.residual", "engine.solve"] * rounds + ["operator.residual"]
+    engines = [s for s in spans if s.name == "engine.solve"]
+    assert len(engines) == rounds + 1
+    for eng in engines:
+        assert _children(spans, eng) == SWEEP_SPANS
+
+
+def test_identity_preamble_opens_no_preamble_span(small_L):
+    from repro.solver import TriangularOperator
+    op = TriangularOperator.from_csr(small_L, tune="no_rewriting",
+                                     cache=False)
+    b = np.ones(small_L.n_rows)
+    op.solve(b, max_refine=0)
+    tr = obs.enable()
+    op.solve(b, max_refine=0)
+    obs.disable()
+    (eng,) = [s for s in tr.spans() if s.name == "engine.solve"]
+    assert _children(tr.spans(), eng) == SWEEP_SPANS[1:]
+
+
+def _op_names(text: str) -> list:
+    """The op_name of every instruction of an HLO text that has one."""
+    import re
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("engine", ["scan", "unrolled"])
+def test_step_body_carries_its_scope_in_the_compiled_program(small_L,
+                                                             engine):
+    import jax.numpy as jnp
+
+    from repro.solver import levelset
+    from repro.solver import schedule_for_csr
+    from repro.sparse import build_levels
+    sched = schedule_for_csr(small_L, build_levels(small_L), chunk=32,
+                             max_deps=4, dtype=np.float32)
+    ds = levelset.to_device(sched)
+    fn = {"scan": levelset._scan_jit,
+          "unrolled": levelset._unrolled_jit}[engine]
+    text = fn.lower(ds.leaves(), ds.n, ds.n_carry,
+                    jnp.zeros(ds.n, jnp.float32)).compile().as_text()
+    names = _op_names(text)
+    assert any("/sptrsv.step/" in n and n.endswith("scatter")
+               for n in names)
+    assert any("/sptrsv.step/" in n and "gather" in n for n in names)
+
+
+def test_device_sweep_scopes_preamble_and_main(lung_op):
+    import jax
+    import jax.numpy as jnp
+    L, op = lung_op
+    text = jax.jit(op.device_solve_fn()).lower(
+        jnp.zeros(L.n_rows, jnp.float32)).compile().as_text()
+    names = _op_names(text)
+    assert any("/sptrsv.preamble/" in n for n in names)
+    assert any("/sptrsv.main/" in n and "/sptrsv.step/" in n
+               for n in names)
+    # the preamble's own step body is a step too
+    assert any("/sptrsv.preamble/" in n and "/sptrsv.step/" in n
+               for n in names)
+
+
+@pytest.mark.parametrize("driver", ["cg", "bicgstab", "gmres"])
+def test_krylov_parts_carry_their_scopes(driver):
+    import jax
+    import jax.numpy as jnp
+
+    from repro import iterative
+    from repro.precond import Preconditioner
+    A = generators.poisson2d_spd(8, 8)
+    P = Preconditioner.ic0(A, tune="avgLevelCost", cache=False)
+    solve = getattr(iterative, driver)
+    text = jax.jit(lambda b: solve(A, b, preconditioner=P, maxiter=4)) \
+        .lower(jnp.ones(A.n_rows, jnp.float32)).compile().as_text()
+    names = _op_names(text)
+    for scope in ("krylov.matvec", "krylov.precond", "krylov.reduce"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    # M^-1's sweeps nest inside its scope
+    assert any("/krylov.precond/" in n and "/sptrsv.step/" in n
+               for n in names)
+
+
+SHARDED_SCOPES = """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.solver import distributed as dist
+    from repro.solver import levelset, schedule_for_csr
+    from repro.sparse import build_levels, generators
+    L = generators.random_lower(120, avg_offdiag=2.0, seed=3, max_back=20)
+    sched = dist._padded_schedule(
+        schedule_for_csr(L, build_levels(L), chunk=16, max_deps=4,
+                         dtype=np.float32), 2)
+    mesh = dist.default_mesh(devices=jax.devices()[:2])
+    leaves = levelset.host_leaves(sched)
+    groups = jax.device_put(leaves, dist.group_shardings(leaves, mesh))
+    text = dist._sharded_solve.lower(
+        groups, jnp.zeros(sched.n, jnp.float32), mesh=mesh, axis="model",
+        n=sched.n, n_carry=sched.n_carry).compile().as_text()
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and "all-gather" in line.split("=", 2)[1]:
+            print("GATHER", m.group(1))
+        elif m:
+            print("OP", m.group(1))
+"""
+
+
+def test_sharded_step_and_exchange_carry_their_scopes():
+    """On two virtual CPU devices: the sharded step body is a
+    `sptrsv.step` and its collective a `sptrsv.exchange` inside it."""
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+    out = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(SHARDED_SCOPES)],
+        capture_output=True, text=True, timeout=300,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": str(Path.home()), "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).parent.parent)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = out.stdout.splitlines()
+    gathers = [ln.split(" ", 1)[1] for ln in lines
+               if ln.startswith("GATHER ")]
+    assert gathers and all("/sptrsv.step/" in g and "/sptrsv.exchange/"
+                           in g for g in gathers)
+    assert any(ln.startswith("OP ") and "/sptrsv.step/" in ln
+               and "sptrsv.exchange" not in ln for ln in lines)
 
 
 # ----------------------------------------------------------------------

@@ -215,15 +215,17 @@ def test_metrics_registry_hammer_exact_totals():
 
 
 def test_disabled_tracer_overhead_on_cached_solve():
-    """PR 9 acceptance: with tracing DISABLED (the default), the no-op
-    span machinery on the solve path must cost <=5% of a cached lung2
-    solve.  Measured directly: per-call cost of the no-op `span()` /
-    `event()` path x a generous per-solve call budget, against the
-    median time of a warm repeat solve."""
+    """With tracing DISABLED (the default), the no-op span machinery on
+    the solve path must cost <=5% of a cached lung2 solve, by count: an
+    enabled tracer first counts the spans and events a
+    cached raw solve and a default refined solve cross (exact: a new span
+    on the hot path is a deliberate edit here); that count times the
+    measured cost of one no-op span is held against the median time of a
+    warm repeat of the same solve."""
     import time
 
     from repro import obs
-    from repro.obs.trace import NULL_SPAN
+    from repro.obs.trace import NULL_SPAN, Tracer
 
     obs.disable()
     assert not obs.enabled()
@@ -232,6 +234,28 @@ def test_disabled_tracer_overhead_on_cached_solve():
     op = TriangularOperator.from_csr(L, tune="no_rewriting", cache=False)
     b = np.ones(L.n_rows)
     op.solve(b, max_refine=0)                   # compile/warm
+    op.solve(b)
+
+    def crossings(**kw) -> int:
+        tracer = obs.enable(Tracer())
+        try:
+            op.solve(b, **kw)
+        finally:
+            obs.disable()
+        spans = tracer.spans()
+        return (len(spans) + sum(len(sp.events) for sp in spans)
+                + len(tracer.orphan_events()))
+
+    # raw: operator.solve > engine.solve > engine.put, engine.run,
+    # engine.get (no_rewriting: the preamble is the identity, no span)
+    raw = crossings(max_refine=0)
+    assert raw == 5
+    # refined, one round: operator.solve, operator.refine, two residuals,
+    # and two sweeps of engine.solve > put, run, get
+    rounds0 = op.stats.refine_rounds
+    refined = crossings()
+    assert op.stats.refine_rounds - rounds0 == 1
+    assert refined == 12
 
     def med(fn, reps=7):
         ts = []
@@ -240,8 +264,6 @@ def test_disabled_tracer_overhead_on_cached_solve():
             fn()
             ts.append(time.perf_counter() - t0)
         return sorted(ts)[len(ts) // 2]
-
-    solve_s = med(lambda: np.asarray(op.solve(b, max_refine=0)))
 
     N = 10_000
     def noop_spans():
@@ -252,8 +274,10 @@ def test_disabled_tracer_overhead_on_cached_solve():
 
     per_call_s = med(noop_spans) / N
     assert obs.span("x") is NULL_SPAN           # really the no-op path
-    # a solve crosses at most a handful of spans; 50 is a generous bound
-    overhead = 50 * per_call_s
-    assert overhead <= 0.05 * solve_s, (
-        f"no-op tracing would cost {overhead * 1e6:.1f}us against a "
-        f"{solve_s * 1e3:.2f}ms cached solve (> 5%)")
+    for count, kw in ((raw, {"max_refine": 0}), (refined, {})):
+        solve_s = med(lambda: np.asarray(op.solve(b, **kw)))
+        overhead = count * per_call_s
+        assert overhead <= 0.05 * solve_s, (
+            f"no-op tracing would cost {overhead * 1e6:.1f}us "
+            f"({count} spans) against a {solve_s * 1e3:.2f}ms cached "
+            f"solve {kw} (> 5%)")
