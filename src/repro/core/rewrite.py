@@ -38,12 +38,52 @@ within one cutoff.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import numpy as np
 
 from ..sparse.csr import CSR
 
-__all__ = ["EquationStore", "RewriteResult"]
+__all__ = ["BRowsPlan", "EquationStore", "RewriteResult"]
+
+
+@dataclasses.dataclass(frozen=True)
+class BRowsPlan:
+    """The non-identity original rows of B' = (I+T)^{-1} for one T pattern
+    (`EquationStore.b_rows_plan`): c = b with c[rows] = B_sub @ b is
+    `EquationStore.preamble_from_T(T, src, b)`."""
+    rows: np.ndarray        # original rows whose B' row is not the identity
+    n: int
+    indptr: np.ndarray      # B_sub's pattern, one row per entry of `rows`
+    indices: np.ndarray
+    out: np.ndarray         # the slot of each B_sub entry
+    slots: int              # entries of every entity's row, aux included
+    unit: np.ndarray        # slots whose constant is 1 (the own column)
+    ext_dst: np.ndarray     # slots whose constant is -T.data[ext_t]
+    ext_t: np.ndarray
+    dst: np.ndarray         # slot[dst] -= T.data[t] * slot[src], in order
+    src: np.ndarray         # of level_ptr: a level reads only the slots
+    t: np.ndarray           # of lower levels
+    level_ptr: np.ndarray
+
+    @property
+    def entries(self) -> int:
+        return int(self.indices.size)
+
+    def matrix(self, t_data: np.ndarray):
+        """B_sub as a float64 scipy CSR for T.data = `t_data`, one
+        vectorized update per level of the T factor."""
+        import scipy.sparse as sp
+        t_data = np.asarray(t_data, dtype=np.float64)
+        vals = np.bincount(self.unit, minlength=self.slots) - np.bincount(
+            self.ext_dst, weights=t_data[self.ext_t], minlength=self.slots)
+        coef = t_data[self.t]
+        ptr = self.level_ptr.tolist()
+        for lo, hi in zip(ptr[:-1], ptr[1:]):
+            np.subtract.at(vals, self.dst[lo:hi],
+                           coef[lo:hi] * vals[self.src[lo:hi]])
+        return sp.csr_array((vals[self.out], self.indices, self.indptr),
+                            shape=(self.rows.size, self.n))
 
 
 @dataclasses.dataclass
@@ -319,6 +359,107 @@ class EquationStore:
             lo, hi = indptr[e], indptr[e + 1]
             c[e] = b[src[e]] - data[lo:hi] @ c[indices[lo:hi]]
         return c[:n]
+
+    @staticmethod
+    def b_rows_plan(T: CSR, src: np.ndarray, n: int,
+                    max_entries: int) -> "BRowsPlan | None":
+        """The pattern of the rows of B' = (I+T)^{-1} that differ from the
+        identity, restricted to original rows, and the recurrence that
+        gives their values from T.data (`BRowsPlan.matrix`).  Value-free:
+        any T of the same pattern (a refactorization) reuses it.
+
+        Walks (T, src) once, in src-ascending entity order: an entity's row
+        is its own unit column minus its references' rows, scaled.  Every
+        reference points to a strictly smaller original row, so a
+        one-reference row is its reference's row with the new column
+        appended, and only rows with several references merge.  Each entry
+        of an entity's row gets a slot, in that order; a slot's value is
+        its constant (1 for the own column, -T[e,k] for an unreferenced
+        entity k) minus T[e,k] times the slot of reference k's row that it
+        copies.  Returns None as soon as the original rows' entries, or the
+        auxiliary entities' entries held to build them, pass `max_entries`.
+        """
+        nz = np.flatnonzero(T.row_nnz() > 0)
+        order = nz[np.argsort(src[nz], kind="stable")]
+        los, his = T.indptr[order].tolist(), T.indptr[order + 1].tolist()
+        ref_ent, ref_src = T.indices.tolist(), src[T.indices].tolist()
+        brows: dict[int, tuple[list, int, int]] = {}  # cols, slot, level
+        runs = []       # (dst0, src0, m, t, level): copy m slots, scaled
+        merged = []     # (dst, src, t, level) of rows with several refs
+        ext_dst, ext_t, unit = [], [], []
+        rows, out_cols, out_slot = [], [], []
+        slot = emitted = held = 0
+        for e, s_e, lo, hi in zip(order.tolist(), src[order].tolist(),
+                                  los, his):
+            if hi - lo == 1:
+                got = brows.get(ref_ent[lo])
+                if got is None:             # unreferenced entity: unit row
+                    own = [ref_src[lo], s_e]
+                    ext_dst.append(slot)
+                    ext_t.append(lo)
+                    lvl = 1
+                else:
+                    own = got[0] + [s_e]
+                    lvl = got[2] + 1
+                    runs.append((slot, got[1], len(got[0]), lo, lvl))
+                unit.append(slot + len(own) - 1)
+            else:
+                cols, srcs, ts, lvl = [], [], [], 1
+                for t in range(lo, hi):
+                    got = brows.get(ref_ent[t])
+                    if got is None:
+                        cols.append(ref_src[t])
+                        srcs.append(-1)
+                        ts.append(t)
+                    else:
+                        cols += got[0]
+                        srcs += range(got[1], got[1] + len(got[0]))
+                        ts += [t] * len(got[0])
+                        lvl = max(lvl, got[2] + 1)
+                own = sorted(set(cols) | {s_e})
+                pos = {c: slot + i for i, c in enumerate(own)}
+                dst = [pos[c] for c in cols]
+                merged.append(([d for d, j in zip(dst, srcs) if j >= 0],
+                               [j for j in srcs if j >= 0],
+                               [t for t, j in zip(ts, srcs) if j >= 0], lvl))
+                ext_dst += [d for d, j in zip(dst, srcs) if j < 0]
+                ext_t += [t for t, j in zip(ts, srcs) if j < 0]
+                unit.append(pos[s_e])
+            brows[e] = (own, slot, lvl)
+            if e < n:
+                rows.append(e)
+                out_cols.append(own)
+                out_slot.append(slot)
+                emitted += len(own)
+            else:
+                held += len(own)
+            slot += len(own)
+            if emitted > max_entries or held > max_entries:
+                return None
+        i64 = lambda a: np.asarray(a, dtype=np.int64)  # noqa: E731
+        runs = i64(runs).reshape(-1, 5)
+        m = runs[:, 2]
+        off = np.arange(m.sum()) - np.repeat(np.cumsum(m) - m, m)
+        parts = [(np.repeat(runs[:, 0], m) + off,
+                  np.repeat(runs[:, 1], m) + off,
+                  np.repeat(runs[:, 3], m), np.repeat(runs[:, 4], m))]
+        parts += [(d, s_, t, [lv] * len(d)) for d, s_, t, lv in merged]
+        dst, srcs, ts, lvls = (np.concatenate([i64(p[j]) for p in parts])
+                               for j in range(4))
+        by_level = np.argsort(lvls, kind="stable")
+        sizes = np.asarray([len(c) for c in out_cols], dtype=np.int64)
+        return BRowsPlan(
+            rows=i64(rows), n=n,
+            indptr=np.concatenate([[0], np.cumsum(sizes)]),
+            indices=np.fromiter(itertools.chain.from_iterable(out_cols),
+                                dtype=np.int64, count=int(sizes.sum())),
+            out=np.repeat(i64(out_slot) - np.cumsum(sizes) + sizes, sizes)
+            + np.arange(sizes.sum()),
+            slots=slot, unit=i64(unit), ext_dst=i64(ext_dst),
+            ext_t=i64(ext_t), dst=dst[by_level], src=srcs[by_level],
+            t=ts[by_level],
+            level_ptr=np.concatenate([[0], np.cumsum(np.bincount(
+                lvls, minlength=int(lvls.max(initial=0)) + 1)[1:])]))
 
     def materialize_b(self, T: CSR, src: np.ndarray,
                       max_entries: int = 50_000_000) -> CSR:

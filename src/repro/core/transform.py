@@ -13,6 +13,12 @@ The preamble has two realizations:
     be large for long rewrite distances (the paper hides this by baking the
     numeric b into generated code; Table-I costs charge neither, and we report
     `operator_total_cost_after` so the any-b overhead is visible).
+`TransformedSystem.preamble` and `materialize_b` are the reference
+realizations.  The host path (`TriangularOperator.solve`, and the tuner's
+measured wall time) runs `host_preamble`: the SpMV over the non-identity
+rows of B' while they hold no more entries than the factor, else the
+T-factor loop; the device path (`device_solve_fn`) runs the T factor as a
+schedule.
 
 Two level assignments are carried:
   * `assigned`  — the paper's bookkeeping (rows land exactly on their target
@@ -28,6 +34,7 @@ selection guidance lives in docs/strategies.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -35,11 +42,11 @@ from ..sparse.csr import CSR
 from ..sparse.levels import LevelSets, build_levels
 from .graph import GraphView
 from .resilience import PatternMismatchError
-from .rewrite import EquationStore
+from .rewrite import BRowsPlan, EquationStore
 from .strategies import Strategy, StrategyStats, strategy_label
 
 __all__ = ["TransformedSystem", "transform", "TransformMetrics",
-           "ReplayPlan", "replay_transform"]
+           "ReplayPlan", "replay_transform", "HostPreamble", "host_preamble"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,6 +137,52 @@ class TransformedSystem:
     @property
     def identity_preamble(self) -> bool:
         return self.T.nnz == 0
+
+
+class HostPreamble:
+    """c = B'b on the host for one transform, as `host_preamble` chose it:
+    "spmv" (c = b, then c[rows] = B_sub @ b, with B_sub worked out from
+    T.data on the first call) or "tfactor" (`TransformedSystem.preamble`).
+    The result has `TransformedSystem.preamble`'s dtype (float64 for a
+    float32 b)."""
+
+    def __init__(self, ts: TransformedSystem, pattern: BRowsPlan | None):
+        self.ts, self.pattern = ts, pattern
+        self.realization = "tfactor" if pattern is None else "spmv"
+        # entries per call: of B_sub, or of T on the T-factor loop
+        self.entries = ts.T.nnz if pattern is None else pattern.entries
+
+    @functools.cached_property
+    def B(self):
+        return self.pattern.matrix(self.ts.T.data)
+
+    def revalued(self, ts: TransformedSystem) -> "HostPreamble":
+        """The same realization for `ts`, a transform with this one's T
+        pattern and new values (a refactorization): no pattern pass, and
+        B_sub is worked out from the new T.data on its first call."""
+        return HostPreamble(ts, self.pattern)
+
+    def __call__(self, b: np.ndarray) -> np.ndarray:
+        if self.pattern is None:
+            return self.ts.preamble(b)
+        c = b.astype(np.result_type(self.ts.T.data, b), copy=True)
+        c[self.pattern.rows] = self.B @ c
+        return c
+
+
+def host_preamble(ts: TransformedSystem, max_entries: int) -> HostPreamble:
+    """The host realization of `ts`'s preamble.  The SpMV over B''s
+    non-identity rows costs a few nanoseconds per entry against the
+    T-factor loop's few microseconds per row; it is taken while those rows,
+    and the auxiliary rows held to build them, stay within `max_entries`
+    (callers pass nnz of the factor).  That bound is a memory cap, not a
+    crossover: the plan then holds no more than a few arrays the size of
+    the factor, and a B' that fills in (long rewrite distances on a 2-D or
+    3-D mesh) stops the pattern pass there and keeps the T-factor loop."""
+    if ts.identity_preamble:
+        return HostPreamble(ts, None)
+    return HostPreamble(ts, EquationStore.b_rows_plan(
+        ts.T, ts.src, ts.A.n_rows, max_entries))
 
 
 def _compact_levels(level_of: np.ndarray) -> np.ndarray:

@@ -652,6 +652,11 @@ class TriangularOperator:
         # operator's _preamble_host builds it from the NEW transform on
         # first use, so the update itself never enters build_schedule.
         new_runtime: dict = {"compiled": {}}
+        # the host preamble's B' pattern is value-free: keep it, and let
+        # the first host preamble work out B' from the NEW T values
+        plan = base.get("_runtime", {}).get("preamble_plan")
+        if plan is not None:
+            new_runtime["preamble_plan"] = plan.revalued(ts_new)
         entry = base.get("_runtime", {}).get("preamble_host")
         if entry is not None:
             psched = entry[0]
@@ -960,6 +965,31 @@ class TriangularOperator:
                 np.count_nonzero(self._ts.T.row_nnz()))
         return rows
 
+    def _preamble_plan(self):
+        """The payload's `HostPreamble` (core.transform.host_preamble,
+        bounded by nnz of the factor), worked out on the first host
+        preamble and kept on the shared payload, so device_solve_fn users
+        never build it; a refactorized payload starts from its base's
+        (`_derive_payload`)."""
+        plan = self._runtime.get("preamble_plan")
+        if plan is None:
+            from ..core.transform import host_preamble
+            plan = self._runtime["preamble_plan"] = host_preamble(
+                self._ts, self._L.nnz)
+        return plan
+
+    def _preamble(self, v: np.ndarray) -> np.ndarray:
+        """c = B'v on the host by the payload's preamble plan; the
+        `engine.preamble` span names the realization.  No span for the
+        identity preamble."""
+        rows = self._preamble_rows()
+        if not rows:
+            return self._ts.preamble(v)
+        with _obs.span("engine.preamble", rows=rows) as sp:
+            plan = self._preamble_plan()
+            sp.set(realization=plan.realization, entries=plan.entries)
+            return plan(v)
+
     def _preamble_host(self):
         """(LevelSchedule|None, src, row_pos) for the T-factor preamble,
         compiled once on the shared payload (None = identity preamble)."""
@@ -1034,12 +1064,7 @@ class TriangularOperator:
         corrections accumulate at full precision."""
         if self._reversed:
             v = v[::-1]
-        rows = self._preamble_rows()
-        span = _obs.span("engine.preamble", rows=rows) if rows \
-            else _obs.NULL_SPAN
-        with span:
-            c = self._ts.preamble(v)
-        x = self._device_solve(c, engine)
+        x = self._device_solve(self._preamble(v), engine)
         if out_dtype is not None:
             x = x.astype(out_dtype)
         return x[::-1] if self._reversed else x

@@ -616,7 +616,10 @@ def test_raw_solve_splits_engine_solve_into_its_parts(lung_op):
     assert _children(spans, eng) == SWEEP_SPANS
     by_name = {s.name: s for s in spans}
     rows = int(np.count_nonzero(op.transformed.T.row_nnz()))
-    assert by_name["engine.preamble"].attrs == {"rows": rows} and rows > 0
+    entries = op._preamble_plan().entries
+    assert by_name["engine.preamble"].attrs == {
+        "rows": rows, "realization": "spmv", "entries": entries}
+    assert rows > 0 and entries > 0
     assert not any(s.name == "operator.residual" for s in spans)
     # the parts lie inside the span that holds them
     for name in SWEEP_SPANS:
@@ -641,6 +644,23 @@ def test_refined_solve_spans_one_residual_per_evaluation(lung_op):
     assert len(engines) == rounds + 1
     for eng in engines:
         assert _children(spans, eng) == SWEEP_SPANS
+
+
+def test_preamble_span_names_the_tfactor_fallback():
+    """A factor whose B' has more entries than the factor itself keeps the
+    T-factor loop, and its span says so, with T's entry count."""
+    from repro.solver import TriangularOperator
+    L = generators.poisson2d_ic0(16, 16)
+    op = TriangularOperator.from_csr(L, tune="avgLevelCost", cache=False)
+    b = np.ones(L.n_rows)
+    op.solve(b, max_refine=0)
+    tr = obs.enable()
+    op.solve(b, max_refine=0)
+    obs.disable()
+    (pre,) = [s for s in tr.spans() if s.name == "engine.preamble"]
+    T = op.transformed.T
+    assert pre.attrs == {"rows": int(np.count_nonzero(T.row_nnz())),
+                         "realization": "tfactor", "entries": T.nnz}
 
 
 def test_identity_preamble_opens_no_preamble_span(small_L):

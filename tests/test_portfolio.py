@@ -179,3 +179,27 @@ def test_measured_mode_agrees_with_cost_ordering():
         # the model-worst candidate on thin-level matrices is the baseline;
         # the pick must not be slower than it (acceptance criterion)
         assert rep.best.measured_us <= measured["no_rewriting"]
+
+
+def test_measure_times_the_operators_host_preamble(monkeypatch, lung_small):
+    """The measured wall time runs the preamble the operator would run:
+    `host_preamble` bounded by the factor's nnz, not the T-factor loop."""
+    import repro.core.portfolio as pf
+    from repro.core.transform import TransformedSystem
+    seen = []
+
+    def spy(ts, max_entries):
+        pre = real(ts, max_entries)
+        seen.append((pre.realization, max_entries))
+        return pre
+
+    def no_loop(self, b):
+        raise AssertionError("the tuner ran the T-factor loop")
+
+    real = pf.host_preamble
+    monkeypatch.setattr(pf, "host_preamble", spy)
+    monkeypatch.setattr(TransformedSystem, "preamble", no_loop)
+    rep = StrategyPortfolio(candidates=[AvgLevelCost()], measure_top_k=1,
+                            measure_iters=2).tune(lung_small)
+    assert seen == [("spmv", lung_small.nnz)]
+    assert rep.best.measured_us is not None and rep.best.measure_note is None

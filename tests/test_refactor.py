@@ -147,6 +147,61 @@ def test_update_values_refined_fp64(rhs):
     assert np.linalg.norm(r) <= 1e-10 * np.linalg.norm(rhs)
 
 
+def _no_pattern_pass(monkeypatch):
+    from repro.core.rewrite import EquationStore
+
+    def refuse(*a, **k):
+        raise AssertionError("a second pattern pass")
+    monkeypatch.setattr(EquationStore, "b_rows_plan", staticmethod(refuse))
+
+
+def test_update_values_rebuilds_preamble_plan(rhs, monkeypatch):
+    """The host preamble's B' pattern is value-free and its values are
+    not: after an update the plan keeps the pattern (no second pattern
+    pass), the first solve works out B' from the NEW transform, never
+    carrying the old values, and the answer is a fresh build's on the new
+    values, bit for bit."""
+    L, L2 = _lower(), _revalued(_lower(), seed=4)
+    op = TriangularOperator.from_csr(L, "avgLevelCost", cache=False)
+    op.solve(rhs, max_refine=0)
+    old = op._runtime["preamble_plan"]
+    assert old.realization == "spmv"
+    old_B = old.B
+    op.update_values(L2)
+    _no_pattern_pass(monkeypatch)
+    new = op._runtime["preamble_plan"]
+    assert new is not old and new.pattern is old.pattern
+    assert new.ts is op.transformed and "B" not in vars(new)
+    x = op.solve(rhs, max_refine=0)
+    assert "B" in vars(new) and np.array_equal(new.B.indices, old_B.indices)
+    assert not np.array_equal(new.B.data, old_B.data)
+    c_ref = op.transformed.preamble(rhs)
+    assert np.abs(op._preamble(rhs) - c_ref).max() \
+        <= 1e-13 * np.abs(c_ref).max()
+    monkeypatch.undo()
+    fresh = TriangularOperator.from_csr(L2, "avgLevelCost", cache=False)
+    assert np.array_equal(x, fresh.solve(rhs, max_refine=0))
+    assert np.array_equal(new.B.data, fresh._preamble_plan().B.data)
+
+
+def test_update_values_keeps_the_tfactor_decision(monkeypatch):
+    """A pattern whose B' passes the factor's nnz keeps the T-factor loop
+    through updates without a second pattern pass, and stays exact."""
+    L = generators.poisson2d_ic0(16, 16)
+    L2 = _revalued(L, seed=5)
+    b = np.random.default_rng(6).standard_normal(L.n_rows)
+    op = TriangularOperator.from_csr(L, "avgLevelCost", cache=False)
+    op.solve(b, max_refine=0)
+    assert op._preamble_plan().realization == "tfactor"
+    op.update_values(L2)
+    _no_pattern_pass(monkeypatch)
+    assert op._runtime["preamble_plan"].realization == "tfactor"
+    x = op.solve(b, max_refine=0)
+    monkeypatch.undo()
+    fresh = TriangularOperator.from_csr(L2, "avgLevelCost", cache=False)
+    assert np.array_equal(x, fresh.solve(b, max_refine=0))
+
+
 def test_update_values_repeated_steps(rhs):
     """A time-stepping sequence of updates stays exact at every step."""
     L = _lower()
