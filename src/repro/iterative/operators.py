@@ -39,7 +39,14 @@ def device_matvec(A: CSR, mesh=None, axis: str = "model"):
     under a mesh synchronizes at the matvec and at the preconditioner's
     per-step all_gathers only, with no host round-trips in between
     (docs/distributed.md).  x is replicated, matching the sharded
-    triangular sweeps' replicated carry contract.
+    triangular sweeps' replicated carry contract.  The mesh form is a
+    `jax.tree_util.Partial` whose leaves are the placed triplet: passed
+    to `jax.jit` as an argument, each device holds only its nnz shard;
+    closed over, the triplet becomes a constant of the program.  The
+    coefficients are placed in A's dtype as JAX realizes it (float32
+    unless x64 is on) and cast to x's dtype inside the program; an x
+    wider than that placement (float64 under a later `enable_x64()`) gets
+    A's coefficients placed anew at its width, once, as a constant.
     """
     import jax.numpy as jnp
     rows_np = np.repeat(np.arange(A.n_rows), A.row_nnz())
@@ -58,8 +65,10 @@ def device_matvec(A: CSR, mesh=None, axis: str = "model"):
 
         return matvec
 
+    import functools
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.tree_util import Partial
     from ..solver.distributed import require_axis
     require_axis(mesh, axis)
     nshards = mesh.shape[axis]
@@ -78,28 +87,32 @@ def device_matvec(A: CSR, mesh=None, axis: str = "model"):
         out = out.at[rows].add(prod)
         return jax.lax.psum(out, axis)
 
-    # the triplet rides in as jit arguments, each device holding only its
-    # nnz shard (placed once, never staged whole on one device)
     spmv = jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=(P(axis), P(axis), P(axis), P()),
                                  out_specs=P(), check_vma=False))
+    # placed once, each device holding only its nnz shard.  Placement may
+    # run inside a jit trace: never keep tracers
     nnz_sharding = NamedSharding(mesh, P(axis))
-    # the index triplet is dtype-independent; the coefficient array is
-    # placed once per RHS dtype — repeat eager matvecs transfer nothing.
-    # Placement may run inside a jit trace: never cache tracers
     with jax.ensure_compile_time_eval():
-        rows_dev, cols_dev = jax.device_put((rows_sh, cols_sh), nnz_sharding)
-    data_by_dtype: dict = {}
+        triplet = jax.device_put((rows_sh, cols_sh, data_sh), nnz_sharding)
+    wider = functools.partial(_wider_data, data_sh, nnz_sharding, {})
+    return Partial(functools.partial(_sharded_matvec, spmv, n_rows, wider),
+                   *triplet)
 
-    def matvec(x):
-        data = data_by_dtype.get(x.dtype)
-        if data is None:
-            with jax.ensure_compile_time_eval():
-                data = jax.device_put(data_sh.astype(x.dtype), nnz_sharding)
-            data_by_dtype[x.dtype] = data
-        return spmv(rows_dev, cols_dev, data, x)[:n_rows]
 
-    return matvec
+def _wider_data(data_np, sharding, placed: dict, dtype):
+    """A's coefficients placed at `dtype`, once per dtype."""
+    import jax
+    if dtype not in placed:
+        with jax.ensure_compile_time_eval():
+            placed[dtype] = jax.device_put(data_np.astype(dtype), sharding)
+    return placed[dtype]
+
+
+def _sharded_matvec(spmv, n_rows, wider, rows, cols, data, x):
+    if x.dtype.itemsize > data.dtype.itemsize:
+        data = wider(x.dtype)
+    return spmv(rows, cols, data.astype(x.dtype), x)[:n_rows]
 
 
 def as_matvec(spec, mesh=None, axis: str = "model"):

@@ -363,17 +363,19 @@ class Preconditioner:
         composed back to back.  No host callbacks — safe inside
         jit/while_loop hot paths regardless of thread-local dtype config,
         which pure_callback is not (XLA may run callbacks on worker
-        threads where a scoped enable_x64() does not apply)."""
+        threads where a scoped enable_x64() does not apply).
+
+        The callable is a `jax.tree_util.Partial` over both sweeps' tiles:
+        pass it to `jax.jit` as an argument and a mesh preconditioner's
+        tiles stay lane-sharded arguments; close over it and they become
+        constants of the program (docs/distributed.md)."""
         key = ("device_apply", None if engine is None else str(engine))
         fn = self._device_fns.get(key)
         if fn is None:
-            f = self.forward.device_solve_fn(engine)
-            g = self.backward.device_solve_fn(engine)
-
-            def fn(r):
-                return g(f(r))
-
-            self._device_fns[key] = fn
+            from jax.tree_util import Partial
+            fn = self._device_fns[key] = Partial(
+                _apply_pair, self.forward.device_solve_fn(engine),
+                self.backward.device_solve_fn(engine))
         return fn
 
     def jax_apply(self, r, *, engine=None):
@@ -417,6 +419,10 @@ class Preconditioner:
     def __repr__(self) -> str:  # pragma: no cover
         return (f"Preconditioner(kind={self.factors.kind!r}, n={self.n}, "
                 f"strategy={self.strategy!r}, shift={self.factors.shift})")
+
+
+def _apply_pair(forward, backward, r):
+    return backward(forward(r))
 
 
 class IdentityPreconditioner:

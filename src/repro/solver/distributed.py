@@ -32,7 +32,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.tree_util import Partial
 
+from ..obs import trace as _obs
+from ..obs.metrics import default_registry
 from .levelset import host_leaves
 from .schedule import LevelSchedule, WidthGroup
 
@@ -120,6 +123,13 @@ def group_shardings(groups, mesh: Mesh, axis: str = "model") -> tuple:
     its argument shapes."""
     return tuple(tuple(NamedSharding(mesh, _lane_spec(l, axis)) for l in g)
                  for g in groups)
+
+
+def _bytes_per_device(arrays) -> int:
+    """Bytes of the placed `arrays` (a pytree) that one device holds: the
+    shard shape of each, as its sharding lays it out."""
+    return int(sum(int(np.prod(a.sharding.shard_shape(a.shape)))
+                   * a.dtype.itemsize for a in jax.tree.leaves(arrays)))
 
 
 def _gather(v, axis):
@@ -214,34 +224,58 @@ def solve_sharded(sched: LevelSchedule, c: np.ndarray, mesh: Mesh,
     return np.asarray(fn(jnp.asarray(c, dtype=sched.dtype)))
 
 
+def _run_sharded(groups, c, *, mesh, axis, n, n_carry, dtype):
+    c = jnp.asarray(c, dtype=dtype)
+    if c.ndim not in (1, 2) or c.shape[0] != n:
+        raise ValueError(
+            f"right-hand side must be ({n},) or ({n}, k) to "
+            f"match the schedule, got shape {c.shape}")
+    return _sharded_solve(groups, c, mesh=mesh, axis=axis, n=n,
+                          n_carry=n_carry)
+
+
 def lower_sharded(sched: LevelSchedule, mesh: Mesh, axis: str = "model"):
-    """Build the jitted sharded solver fn(c) -> x for a fixed schedule.
+    """Build the sharded solver fn(c) -> x for a fixed schedule.
 
     The returned fn accepts `(n,)` or batched `(n, k)` right-hand sides
     (lanes sharded over `axis`, RHS columns replicated) and validates the
-    leading dimension eagerly.  Prefer `ShardedEngine.compile` (or
+    leading dimension eagerly.  It is a `jax.tree_util.Partial` whose
+    leaves are the placed tiles: called inside an enclosing `jax.jit`
+    that closes over it, the tiles become constants of that program
+    (every device holds them whole); passed to the `jax.jit` as an
+    argument, they stay arguments, each device holding only its lanes.
+    Padding and placing run in the span `engine.place`; the counters
+    `sharded.exchanges` (all_gather families, one per step) and
+    `sharded.tile_bytes_per_device` of `obs.default_registry()` grow by
+    this schedule's.  Prefer `ShardedEngine.compile` (or
     `solve_sharded`), which memoizes this lowering per schedule identity.
     """
     require_axis(mesh, axis)
-    padded = _padded_schedule(sched, mesh.shape[axis])
-    leaves = host_leaves(padded)
-    # lowering may be triggered lazily from INSIDE a jit trace (an
-    # operator first used as a traced preconditioner); the placed arrays
-    # are memoized on the engine, so they must be concrete, never tracers
-    with jax.ensure_compile_time_eval():
-        groups = jax.device_put(leaves, group_shardings(leaves, mesh, axis))
-    n, n_carry, dtype = padded.n, padded.n_carry, padded.dtype
-
-    def run(c):
-        c = jnp.asarray(c, dtype=dtype)
-        if c.ndim not in (1, 2) or c.shape[0] != n:
-            raise ValueError(
-                f"right-hand side must be ({n},) or ({n}, k) to "
-                f"match the schedule, got shape {c.shape}")
-        return _sharded_solve(groups, c, mesh=mesh, axis=axis, n=n,
-                              n_carry=n_carry)
-
-    return run
+    nshards = mesh.shape[axis]
+    with _obs.span("engine.place", steps=sched.num_steps,
+                   shards=nshards) as sp:
+        padded = _padded_schedule(sched, nshards)
+        leaves = host_leaves(padded)
+        # lowering may be triggered lazily from INSIDE a jit trace (an
+        # operator first used as a traced preconditioner); the placed
+        # arrays are memoized on the engine, so they must be concrete,
+        # never tracers
+        with jax.ensure_compile_time_eval():
+            groups = jax.device_put(leaves,
+                                    group_shardings(leaves, mesh, axis))
+        per_device = _bytes_per_device(groups)
+        sp.set(tile_bytes_per_device=per_device)
+    reg = default_registry()
+    with reg.lock:
+        reg.counter("sharded.exchanges",
+                    "all_gather families of the lowered sharded sweeps "
+                    "(one per step)").inc(padded.num_steps)
+        reg.counter("sharded.tile_bytes_per_device",
+                    "bytes of placed schedule tiles one device holds"
+                    ).inc(per_device)
+    return Partial(functools.partial(
+        _run_sharded, mesh=mesh, axis=axis, n=padded.n,
+        n_carry=padded.n_carry, dtype=padded.dtype), groups)
 
 
 def count_all_gathers(sched: LevelSchedule, mesh: Mesh | None = None,

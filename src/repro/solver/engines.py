@@ -186,10 +186,10 @@ class PallasEngine(Engine):
         self._require_dtype(dsched)
         interpret = (default_interpret() if self.interpret is None
                      else self.interpret)
-        groups, n, n_carry = dsched.groups, dsched.n, dsched.n_carry
-        dtype = dsched.dtype
+        from jax.tree_util import Partial
+        n, n_carry, dtype = dsched.n, dsched.n_carry, dsched.dtype
 
-        def fn(c):
+        def fn(groups, c):
             c = jnp.asarray(c, dtype=dtype)
             tail = (c.shape[1],) if c.ndim == 2 else ()
             c_pad = jnp.concatenate([c, jnp.zeros((1,) + tail, dtype)],
@@ -198,7 +198,9 @@ class PallasEngine(Engine):
             return kern(groups, c_pad, n=n, n_carry=n_carry,
                         interpret=interpret)
 
-        return fn
+        # the staged groups are the callable's pytree leaves, as with the
+        # scan engine (levelset.staged_scan_fn)
+        return Partial(fn, dsched.groups)
 
 
 class ShardedEngine(Engine):

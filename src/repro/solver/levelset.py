@@ -18,9 +18,12 @@ materialized-B' SpMV or a second schedule built on the T factor.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from .schedule import LevelSchedule
 
@@ -151,17 +154,24 @@ def solve_unrolled(dsched: DeviceSchedule, c: jax.Array) -> jax.Array:
     return _unrolled_impl(dsched.leaves(), dsched.n, dsched.n_carry, c)
 
 
+def _staged(jitted, n: int, n_carry: int, leaves, c):
+    return jitted(leaves, n, n_carry, c)
+
+
 def staged_scan_fn(dsched: DeviceSchedule):
     """Serving callable for the scan engine: jit with the staged leaves as
-    arguments, so schedules sharing a tile layout share one executable."""
-    leaves, n, n_carry = dsched.leaves(), dsched.n, dsched.n_carry
-    return lambda c: _scan_jit(leaves, n, n_carry, c)
+    arguments, so schedules sharing a tile layout share one executable.
+    A `jax.tree_util.Partial` over the leaves: an enclosing jit that
+    closes over it embeds them as constants, one that takes it as an
+    argument takes them as arguments."""
+    return Partial(functools.partial(_staged, _scan_jit, dsched.n,
+                                     dsched.n_carry), dsched.leaves())
 
 
 def staged_unrolled_fn(dsched: DeviceSchedule):
     """Serving callable for the unrolled engine (see staged_scan_fn)."""
-    leaves, n, n_carry = dsched.leaves(), dsched.n, dsched.n_carry
-    return lambda c: _unrolled_jit(leaves, n, n_carry, c)
+    return Partial(functools.partial(_staged, _unrolled_jit, dsched.n,
+                                     dsched.n_carry), dsched.leaves())
 
 
 def solve(sched: LevelSchedule, c: np.ndarray, engine=None,

@@ -131,6 +131,24 @@ def orient_lower(A: CSR, side: str, transpose: bool) -> tuple:
     return reverse_both(A), True    # upper, no transpose
 
 
+def _sweep(schedule_dtype, reversed_: bool, main_fn, pre_fn, src, row_pos,
+           v):
+    import jax
+    import jax.numpy as jnp
+    out_dtype = v.dtype
+    c = jnp.asarray(v, dtype=schedule_dtype)
+    if reversed_:
+        c = jnp.flip(c, axis=0)
+    if pre_fn is not None:
+        with jax.named_scope("sptrsv.preamble"):
+            c = pre_fn(c[src])[row_pos]
+    with jax.named_scope("sptrsv.main"):
+        x = main_fn(c)
+    if reversed_:
+        x = jnp.flip(x, axis=0)
+    return x.astype(out_dtype)
+
+
 def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, src, row_pos,
                      reversed_: bool):
     """Compose one triangular sweep as a pure JAX callable: axis reversal
@@ -145,25 +163,17 @@ def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, src, row_pos,
     preamble and the main schedule run under the name scopes
     `sptrsv.preamble` and `sptrsv.main`, which label their device ops in
     a profile (docs/observability.md).
+
+    The result is a `jax.tree_util.Partial` whose leaves are those of the
+    engines' callables (their staged or placed tiles) and the preamble's
+    index vectors: an enclosing `jax.jit` that closes over it embeds them
+    as constants, one that takes it as an argument takes them as
+    arguments (docs/distributed.md).
     """
-    import jax
-    import jax.numpy as jnp
-
-    def fn(v):
-        out_dtype = v.dtype
-        c = jnp.asarray(v, dtype=schedule_dtype)
-        if reversed_:
-            c = jnp.flip(c, axis=0)
-        if pre_fn is not None:
-            with jax.named_scope("sptrsv.preamble"):
-                c = pre_fn(c[src])[row_pos]
-        with jax.named_scope("sptrsv.main"):
-            x = main_fn(c)
-        if reversed_:
-            x = jnp.flip(x, axis=0)
-        return x.astype(out_dtype)
-
-    return fn
+    import functools
+    from jax.tree_util import Partial
+    return Partial(functools.partial(_sweep, schedule_dtype, reversed_),
+                   main_fn, pre_fn, src, row_pos)
 
 
 def default_cache_dir() -> Path:
