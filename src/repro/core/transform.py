@@ -17,8 +17,9 @@ The preamble has two realizations:
 realizations.  The host path (`TriangularOperator.solve`, and the tuner's
 measured wall time) runs `host_preamble`: the SpMV over the non-identity
 rows of B' while they hold no more entries than the factor, else the
-T-factor loop; the device path (`device_solve_fn`) runs the T factor as a
-schedule.
+T-factor loop.  The device path (`device_solve_fn`, solver/preamble.py)
+takes the same choice: the product with those rows, else the T factor
+as a schedule.
 
 Two level assignments are carried:
   * `assigned`  — the paper's bookkeeping (rows land exactly on their target

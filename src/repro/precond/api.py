@@ -162,11 +162,11 @@ class Preconditioner:
         Model ranking comes from `StrategyPortfolio.tune_pair`; when
         `measure_top_k > 0` the model's top-k candidates PLUS the
         `no_rewriting` baseline are re-timed through the COMPOSED device
-        pipeline (flip + compiled T-factor preamble + schedule, both
-        sweeps back to back) — i.e. exactly what a Krylov loop will
-        execute, preamble realization included.  Measuring the served
-        pipeline (not the host preamble) matters: a transform whose
-        T-factor is expensive can model-rank well yet lose end to end,
+        pipeline (flip + device preamble + schedule, both sweeps back to
+        back) — i.e. exactly what a Krylov loop will execute, preamble
+        realization included.  Measuring the served pipeline (not the
+        host preamble) matters: a transform whose preamble is expensive
+        on the device can model-rank well yet lose end to end,
         and including the baseline guarantees the pick is never slower
         than `no_rewriting` up to timer noise.
         """
@@ -233,8 +233,10 @@ class Preconditioner:
         import time as _time
         import jax
         import jax.numpy as jnp
+        from ..core.transform import host_preamble
         from ..solver.engines import compile_source, resolve_engine
         from ..solver.levelset import to_device
+        from ..solver.preamble import device_preamble
         from ..solver.schedule import schedule_for_preamble
         eng = resolve_engine(engine)
         labels = [c["label"] for c in pair.combined[:top_k]]
@@ -246,31 +248,35 @@ class Preconditioner:
         by_label_b = {c.label: c for c in pair.bwd.candidates
                       if c.error is None}
 
-        def side_fn(cand, reversed_):
-            psched, src, row_pos = schedule_for_preamble(
-                cand.ts, chunk=chunk, max_deps=max_deps,
-                dtype=np.dtype(dtype))
+        def side_fn(cand, reversed_, nnz):
             # host-lowering engines take the host schedules directly (the
             # same engines.compile_source branch the serving path's
-            # _compiled_fn/_preamble_host takes)
+            # _compiled_fn/_device_preamble takes)
             main_fn = eng.compile(compile_source(
                 eng, cand.sched, lambda: to_device(cand.sched)))
-            pre = None
-            if psched is not None:
-                pre = eng.compile(compile_source(
-                    eng, psched, lambda: to_device(psched)))
-            # the SAME composition production runs (device_solve_fn):
-            # what gets timed is what gets served
-            return compose_sweep_fn(main_fn, cand.sched.dtype, pre, src,
-                                    row_pos, reversed_)
+
+            def scheduled():
+                psched, src, row_pos = schedule_for_preamble(
+                    cand.ts, chunk=chunk, max_deps=max_deps,
+                    dtype=np.dtype(dtype))
+                return eng.compile(compile_source(
+                    eng, psched, lambda: to_device(psched))), src, row_pos
+
+            # the SAME preamble and composition production runs
+            # (device_solve_fn): what gets timed is what gets served
+            pre = device_preamble(host_preamble(cand.ts, nnz),
+                                  cand.sched.dtype, eng, scheduled)
+            return compose_sweep_fn(main_fn, cand.sched.dtype, pre,
+                                    reversed_)
 
         n = pair.fwd.matrix["n"]
         r = jnp.asarray(np.random.default_rng(0).standard_normal(n),
                         dtype=np.dtype(dtype))
         measured = {}
         for label in labels:
-            f = side_fn(by_label_f[label], False)
-            g = side_fn(by_label_b[label], bwd_reversed)
+            f = side_fn(by_label_f[label], False, pair.fwd.matrix["nnz"])
+            g = side_fn(by_label_b[label], bwd_reversed,
+                        pair.bwd.matrix["nnz"])
             apply_fn = jax.jit(lambda v: g(f(v)))
             jax.block_until_ready(apply_fn(r))      # compile outside timer
             best = float("inf")
@@ -358,8 +364,8 @@ class Preconditioner:
 
     def device_apply(self, engine=None):
         """The full M^-1 application as a pure JAX callable: forward and
-        backward device pipelines (reversal + compiled T-factor preamble +
-        compiled schedule, see TriangularOperator.device_solve_fn)
+        backward device pipelines (reversal + preamble + compiled
+        schedule, see TriangularOperator.device_solve_fn)
         composed back to back.  No host callbacks — safe inside
         jit/while_loop hot paths regardless of thread-local dtype config,
         which pure_callback is not (XLA may run callbacks on worker

@@ -131,8 +131,7 @@ def orient_lower(A: CSR, side: str, transpose: bool) -> tuple:
     return reverse_both(A), True    # upper, no transpose
 
 
-def _sweep(schedule_dtype, reversed_: bool, main_fn, pre_fn, src, row_pos,
-           v):
+def _sweep(schedule_dtype, reversed_: bool, main_fn, pre_fn, v):
     import jax
     import jax.numpy as jnp
     out_dtype = v.dtype
@@ -141,7 +140,7 @@ def _sweep(schedule_dtype, reversed_: bool, main_fn, pre_fn, src, row_pos,
         c = jnp.flip(c, axis=0)
     if pre_fn is not None:
         with jax.named_scope("sptrsv.preamble"):
-            c = pre_fn(c[src])[row_pos]
+            c = pre_fn(c)
     with jax.named_scope("sptrsv.main"):
         x = main_fn(c)
     if reversed_:
@@ -149,31 +148,33 @@ def _sweep(schedule_dtype, reversed_: bool, main_fn, pre_fn, src, row_pos,
     return x.astype(out_dtype)
 
 
-def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, src, row_pos,
-                     reversed_: bool):
+def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, reversed_: bool):
     """Compose one triangular sweep as a pure JAX callable: axis reversal
-    (transpose/upper orientations) -> T-factor preamble -> main schedule
-    -> un-reverse, in the schedule dtype, cast back to the input's dtype.
+    (transpose/upper orientations) -> the transform's preamble c = B'c ->
+    main schedule -> un-reverse, in the schedule dtype, cast back to the
+    input's dtype.
 
     The ONE definition of the served device pipeline: both
     `TriangularOperator.device_solve_fn` (production applications) and
     `Preconditioner._measure_pair` (measured pair tuning) build on it, so
     the tuner always times exactly the computation it selects for.
-    `pre_fn`/`src`/`row_pos` are None for identity preambles.  The
-    preamble and the main schedule run under the name scopes
-    `sptrsv.preamble` and `sptrsv.main`, which label their device ops in
-    a profile (docs/observability.md).
+    `pre_fn` is `solver.preamble.device_preamble`'s callable: one product
+    with B''s non-identity rows where the host plan exists, else the
+    T-factor schedule; None for identity preambles.  The preamble and the
+    main schedule run under the name scopes `sptrsv.preamble` and
+    `sptrsv.main`, which label their device ops in a profile
+    (docs/observability.md).
 
     The result is a `jax.tree_util.Partial` whose leaves are those of the
     engines' callables (their staged or placed tiles) and the preamble's
-    index vectors: an enclosing `jax.jit` that closes over it embeds them
-    as constants, one that takes it as an argument takes them as
-    arguments (docs/distributed.md).
+    arrays: an enclosing `jax.jit` that closes over it embeds them as
+    constants, one that takes it as an argument takes them as arguments
+    (docs/distributed.md).
     """
     import functools
     from jax.tree_util import Partial
     return Partial(functools.partial(_sweep, schedule_dtype, reversed_),
-                   main_fn, pre_fn, src, row_pos)
+                   main_fn, pre_fn)
 
 
 def default_cache_dir() -> Path:
@@ -978,9 +979,8 @@ class TriangularOperator:
     def _preamble_plan(self):
         """The payload's `HostPreamble` (core.transform.host_preamble,
         bounded by nnz of the factor), worked out on the first host
-        preamble and kept on the shared payload, so device_solve_fn users
-        never build it; a refactorized payload starts from its base's
-        (`_derive_payload`)."""
+        preamble or device sweep and kept on the shared payload; a
+        refactorized payload starts from its base's (`_derive_payload`)."""
         plan = self._runtime.get("preamble_plan")
         if plan is None:
             from ..core.transform import host_preamble
@@ -1027,19 +1027,47 @@ class TriangularOperator:
             self._runtime["preamble"] = entry
         return entry
 
+    def _device_preamble(self, engine):
+        """The sweep's device preamble for `engine`
+        (solver.preamble.device_preamble), built once per engine on the
+        shared payload: B''s rows as one product where the payload's
+        preamble plan exists, else the T-factor schedule, which is then
+        the only case that builds, stages and compiles it."""
+        from .engines import compile_source
+        from .preamble import device_preamble
+        built = self._runtime.setdefault("device_preamble", {})
+        cached = built.get(engine.name)
+        if cached is not None and cached[0] is engine:
+            return cached[1]
+
+        def scheduled():
+            psched, src, row_pos = self._preamble_host()
+            # same host-vs-staged branch as _compiled_fn
+            return engine.compile(compile_source(
+                engine, psched, lambda: self._preamble_staged()[0])), \
+                src, row_pos
+
+        pre_fn = device_preamble(self._preamble_plan(), self._canon_dtype(),
+                                 engine, scheduled)
+        built[engine.name] = (engine, pre_fn)
+        return pre_fn
+
     def device_solve_fn(self, engine=None):
         """The operator's sweep as a pure JAX callable — jit/while_loop
         composable, no host callbacks.
 
         Returns fn(v) -> x for v of shape (n,) or (n, k): axis reversal
-        (transpose/upper sweeps), the T-factor preamble (compiled through
-        the SAME level-scheduled engines via schedule_for_preamble), and
-        the main schedule all run on device in the schedule dtype; the
-        result is cast back to v's dtype.  No float64 iterative
-        refinement — this is the raw device pipeline, which is exactly
-        what preconditioner applications inside jit-native Krylov loops
-        want (M^-1 is approximate by construction; see
-        repro.iterative/docs/iterative.md).
+        (transpose/upper sweeps), the transform's preamble c = B'v, and the
+        main schedule all run on device in the schedule dtype; the result
+        is cast back to v's dtype.  The preamble is one product with B''s
+        non-identity rows, in the schedule dtype, where the payload's host
+        preamble plan exists (B' within nnz of the factor); only where it
+        does not is it the T factor's own level schedule
+        (schedule_for_preamble), compiled through the SAME engine
+        (solver/preamble.py).  No float64 iterative refinement — this is
+        the raw device pipeline, which is exactly what preconditioner
+        applications inside jit-native Krylov loops want (M^-1 is
+        approximate by construction; see repro.iterative/docs/iterative.md).
         """
         from .engines import resolve_engine
         eng = self._engine if engine is None else resolve_engine(engine)
@@ -1047,22 +1075,8 @@ class TriangularOperator:
             raise ValueError(
                 "operator has no resolvable default engine "
                 f"({self._engine_name!r}); pass engine= explicitly")
-        from .engines import compile_source
-        main_fn = self._compiled_fn(eng)
-        psched, src, row_pos = self._preamble_host()
-        pre_fn = None
-        if psched is not None:
-            pre_compiled = self._runtime.setdefault("pre_compiled", {})
-            cached = pre_compiled.get(eng.name)
-            if cached is not None and cached[0] is eng:
-                pre_fn = cached[1]
-            else:
-                # same host-vs-staged branch as _compiled_fn
-                pre_fn = eng.compile(compile_source(
-                    eng, psched, lambda: self._preamble_staged()[0]))
-                pre_compiled[eng.name] = (eng, pre_fn)
-        return compose_sweep_fn(main_fn, self._canon_dtype(), pre_fn, src,
-                                row_pos, self._reversed)
+        return compose_sweep_fn(self._compiled_fn(eng), self._canon_dtype(),
+                                self._device_preamble(eng), self._reversed)
 
     def _oriented_solve(self, v: np.ndarray, engine,
                         out_dtype=None) -> np.ndarray:

@@ -99,8 +99,10 @@ def test_mesh_auto_tune_defaults_to_sharded_cost_model(tmp_path):
 
 def test_sharded_operator_never_stages_unpadded_schedules():
     """Host-lowering engines must not trigger the unpadded DeviceSchedule
-    staging — neither for the main schedule nor for the T-factor
-    preamble; the sharded lowering pads and stages its own copies."""
+    staging — neither for the main schedule nor for a T-factor preamble;
+    the sharded lowering pads and stages its own copies.  Here B''s rows
+    fit the plan, so the device preamble is one product and no preamble
+    schedule is built at all."""
     import jax.numpy as jnp
     from repro.solver import TriangularOperator
     L = generators.lung2_like(scale=0.02)
@@ -117,7 +119,8 @@ def test_sharded_operator_never_stages_unpadded_schedules():
     assert np.abs(y - x_ref).max() / scale < 1e-3
     assert op._runtime.get("dsched") is None
     assert op._runtime.get("preamble") is None
-    assert "preamble_host" in op._runtime
+    assert op._preamble_plan().realization == "spmv"
+    assert "preamble_host" not in op._runtime
 
 
 def test_mesh_pair_decision_defaults_to_sharded_cost_model():

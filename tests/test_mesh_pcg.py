@@ -32,7 +32,6 @@ SCRIPT = textwrap.dedent("""
     from repro.iterative.operators import device_matvec
     from repro.obs import default_registry
     from repro.precond import Preconditioner
-    from repro.solver import schedule_for_preamble
     from repro.solver.distributed import count_all_gathers, default_mesh
     from repro.sparse import generators
 
@@ -57,26 +56,26 @@ SCRIPT = textwrap.dedent("""
     A = generators.poisson2d_spd(48, 48)
     n = A.n_rows
     A_sp = sp.csr_matrix((A.data, A.indices, A.indptr), shape=A.shape)
-    before = total("sharded.exchanges"), total("sharded.tile_bytes_per_device")
+    names = ("sharded.exchanges", "sharded.tile_bytes_per_device",
+             "sweep.device_preamble_spmv")
+    before = [total(k) for k in names]
     P = Preconditioner.ic0(A, tune="avgLevelCost", mesh=mesh, cache=False)
     M = P.device_apply()
     A_op = device_matvec(A, mesh=mesh)
-    res["exchanges"] = total("sharded.exchanges") - before[0]
-    res["tile_bytes"] = total("sharded.tile_bytes_per_device") - before[1]
+    res["exchanges"], res["tile_bytes"], res["products"] = [
+        total(k) - b for k, b in zip(names, before)]
     res["steps_py"] = (steps.sweep_steps(P.forward)
                        + steps.sweep_steps(P.backward))
-    fam = 0
-    for op in (P.forward, P.backward):
-        main = op.schedule
-        pre, _, _ = schedule_for_preamble(op.transformed, chunk=main.chunk,
-                                          max_deps=main.max_deps,
-                                          dtype=main.dtype)
-        for s in (main, pre):
-            if s is not None:
-                fam += count_all_gathers(s, mesh)["families"]
-    res["families"] = fam
+    res["main_steps"] = (P.forward.schedule.num_steps
+                         + P.backward.schedule.num_steps)
+    res["families"] = sum(count_all_gathers(op.schedule, mesh)["families"]
+                          for op in (P.forward, P.backward))
 
-    tiles = [a for a in jax.tree.leaves(M) if isinstance(a, jax.Array)]
+    # M is Partial(pair, forward, backward), each Partial(sweep, main, pre)
+    tiles = [a for sweep in M.args for a in jax.tree.leaves(sweep.args[0])]
+    pre = [a for sweep in M.args for a in jax.tree.leaves(sweep.args[1])]
+    res["preamble_replicated"] = bool(pre) and all(
+        a.sharding.is_fully_replicated for a in pre)
     res["whole_tile_bytes"] = sum(a.nbytes for a in tiles)
     res["lane_quarters"] = all(
         a.sharding.shard_shape(a.shape)[1] * 4 == a.shape[1]
@@ -163,6 +162,8 @@ def test_each_device_holds_a_quarter_of_every_group_and_of_A(mesh_pcg):
     assert mesh_pcg["lane_quarters"] and mesh_pcg["nnz_quarters"]
     assert mesh_pcg["compiled_takes_lanes"]
     assert mesh_pcg["tile_bytes"] * 4 == mesh_pcg["whole_tile_bytes"]
+    # the preamble's B' product is replicated, as x is: no collective
+    assert mesh_pcg["preamble_replicated"]
 
 
 @pytest.mark.parametrize("form", ["arguments", "closure", "single"])
@@ -178,8 +179,13 @@ def test_mesh_matvec_keeps_float64_precision(mesh_pcg):
 
 
 def test_exchanges_counter_is_the_steps_of_both_sweeps(mesh_pcg):
-    assert mesh_pcg["exchanges"] == mesh_pcg["steps_py"] == \
+    # both preambles are B' products (no schedule, no exchange), so the
+    # exchanges are the main schedules' steps; chipbench/steps.py still
+    # counts the preamble schedules the device no longer runs
+    assert mesh_pcg["products"] == 2
+    assert mesh_pcg["exchanges"] == mesh_pcg["main_steps"] == \
         mesh_pcg["families"] > 0
+    assert mesh_pcg["steps_py"] > mesh_pcg["exchanges"]
 
 
 @pytest.mark.parametrize("engine", ["scan", "unrolled", "pallas"])

@@ -713,9 +713,27 @@ def test_device_sweep_scopes_preamble_and_main(lung_op):
     assert any("/sptrsv.preamble/" in n for n in names)
     assert any("/sptrsv.main/" in n and "/sptrsv.step/" in n
                for n in names)
-    # the preamble's own step body is a step too
+    # B' fits the plan: the preamble is one product, with no step
+    assert op._preamble_plan().realization == "spmv"
+    assert not any("/sptrsv.preamble/" in n and "/sptrsv.step/" in n
+                   for n in names)
+
+
+def test_scheduled_device_preamble_is_steps_under_its_scope(lung_op):
+    """Where B' does not fit the plan (here a cap of one entry), the
+    device preamble is the T factor's schedule: steps under
+    `sptrsv.preamble`."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.transform import host_preamble
+    from repro.solver import TriangularOperator
+    L, _ = lung_op
+    op = TriangularOperator.from_csr(L, tune="avgLevelCost", cache=False)
+    op._runtime["preamble_plan"] = host_preamble(op.transformed, 1)
+    text = jax.jit(op.device_solve_fn()).lower(
+        jnp.zeros(L.n_rows, jnp.float32)).compile().as_text()
     assert any("/sptrsv.preamble/" in n and "/sptrsv.step/" in n
-               for n in names)
+               for n in _op_names(text))
 
 
 @pytest.mark.parametrize("driver", ["cg", "bicgstab", "gmres"])
