@@ -372,29 +372,9 @@ def main() -> None:
     from benchmarks import serving_bench
     with traced_section("serving", trace_dir):
         serving_bench.run(out_path="experiments/BENCH_serving.json")
-    _roofline_summary()
     write_bench_summary()
     print("wrote experiments/BENCH_summary.json")
     print(f"\ntotal {time.time() - t0:.1f}s")
-
-
-def _roofline_summary() -> None:
-    """Summarize the latest dry-run roofline records, if present."""
-    src = Path("experiments/dryrun_results.json")
-    if not src.exists():
-        print("\n(no dry-run records; run repro.launch.dryrun --all "
-              "--both-meshes first)")
-        return
-    rs = [r for r in json.loads(src.read_text()) if "roofline" in r]
-    print("\n== Dry-run roofline summary (arch,shape,mesh,dominant,"
-          "useful,MFU_hi,MFU_lo) ==")
-    for r in rs:
-        rf = r["roofline"]
-        print(f"{r['arch']},{r['shape']},{r['mesh_tag']},"
-              f"{rf['dominant_min']},{rf['useful_fraction']:.2f},"
-              f"{rf['roofline_mfu']:.3f},{rf['roofline_mfu_min']:.3f}")
-    skips = [r for r in json.loads(src.read_text()) if "skip" in r]
-    print(f"cells: {len(rs)} compiled OK, {len(skips)} assignment skips")
 
 
 if __name__ == "__main__":

@@ -38,8 +38,7 @@ from ..sparse.csr import CSR
 from .strategies import (AvgLevelCost, ConstrainedAvgLevelCost,
                          CriticalPathRewrite, ManualEveryK, NoRewrite,
                          Strategy, strategy_label)
-from .transform import (TransformMetrics, TransformedSystem, host_preamble,
-                        transform)
+from .transform import TransformMetrics, TransformedSystem, transform
 
 __all__ = ["CostModel", "PortfolioCandidate", "PortfolioReport",
            "PairReport", "StrategyPortfolio", "default_candidates",
@@ -509,8 +508,9 @@ class StrategyPortfolio:
         """End-to-end per-solve wall time (host preamble + compiled engine),
         dispatched through the engine registry; sets `cand.measured_us`
         (and `cand.measure_note` when something noteworthy happened).  The
-        preamble is the operator's own host realization
-        (`host_preamble`, bounded by `max_entries`, the factor's nnz).
+        preamble is the operator's own host form
+        (`solver.preamble.SweepPreamble.host`, bounded by `max_entries`,
+        the factor's nnz).
 
         Hardened against flaky hosts: per-candidate sampling stops at the
         `measure_timeout_s` deadline, and a sample spread wider than
@@ -523,13 +523,14 @@ class StrategyPortfolio:
         import jax.numpy as jnp
         from ..solver.engines import compile_source, resolve_engine
         from ..solver.levelset import to_device
+        from ..solver.preamble import SweepPreamble
         eng = resolve_engine(self.engine)
         # host-lowering engines (sharded) stage their own padded copy;
         # handing them an unpadded DeviceSchedule would just pin device
         # memory they never read (engines.compile_source)
         fn = eng.compile(compile_source(eng, cand.sched,
                                         lambda: to_device(cand.sched)))
-        pre = host_preamble(cand.ts, max_entries)
+        pre = SweepPreamble(cand.ts, max_entries).host
         b = np.random.default_rng(0).standard_normal(cand.ts.A.n_rows)
         c = jnp.asarray(pre(b), dtype=cand.sched.dtype)  # builds B' once
         jnp.asarray(fn(c)).block_until_ready()         # compile outside timer

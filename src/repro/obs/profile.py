@@ -275,7 +275,7 @@ def profile_operator(op, b=None, *, reps: int = 2, warmup: int = 1,
     v = np.ones(op.n, dtype=np.float64) if b is None else np.asarray(b)
     if op._reversed:
         v = v[::-1]
-    c = op._ts.preamble(v)
+    c = op.preamble.host(v)
     mesh, axis = None, "model"
     if isinstance(op._engine, ShardedEngine):
         mesh, axis = op._engine.resolve_mesh(), op._engine.axis

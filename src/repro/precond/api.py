@@ -233,11 +233,9 @@ class Preconditioner:
         import time as _time
         import jax
         import jax.numpy as jnp
-        from ..core.transform import host_preamble
         from ..solver.engines import compile_source, resolve_engine
         from ..solver.levelset import to_device
-        from ..solver.preamble import device_preamble
-        from ..solver.schedule import schedule_for_preamble
+        from ..solver.preamble import SweepPreamble
         eng = resolve_engine(engine)
         labels = [c["label"] for c in pair.combined[:top_k]]
         if "no_rewriting" not in labels and any(
@@ -249,24 +247,14 @@ class Preconditioner:
                       if c.error is None}
 
         def side_fn(cand, reversed_, nnz):
-            # host-lowering engines take the host schedules directly (the
-            # same engines.compile_source branch the serving path's
-            # _compiled_fn/_device_preamble takes)
+            # the SAME engine branch, preamble and composition production
+            # runs (device_solve_fn): what gets timed is what gets served
             main_fn = eng.compile(compile_source(
                 eng, cand.sched, lambda: to_device(cand.sched)))
-
-            def scheduled():
-                psched, src, row_pos = schedule_for_preamble(
-                    cand.ts, chunk=chunk, max_deps=max_deps,
-                    dtype=np.dtype(dtype))
-                return eng.compile(compile_source(
-                    eng, psched, lambda: to_device(psched))), src, row_pos
-
-            # the SAME preamble and composition production runs
-            # (device_solve_fn): what gets timed is what gets served
-            pre = device_preamble(host_preamble(cand.ts, nnz),
-                                  cand.sched.dtype, eng, scheduled)
-            return compose_sweep_fn(main_fn, cand.sched.dtype, pre,
+            pre = SweepPreamble(cand.ts, nnz, chunk=chunk, max_deps=max_deps,
+                                dtype=np.dtype(dtype))
+            return compose_sweep_fn(main_fn, cand.sched.dtype,
+                                    pre.device(eng, cand.sched.dtype),
                                     reversed_)
 
         n = pair.fwd.matrix["n"]

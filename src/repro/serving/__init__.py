@@ -11,8 +11,7 @@ Layers, bottom-up:
 * `service`  — the front door: `submit()` futures, per-tenant in-flight
   caps (typed `AdmissionError`), worker pool, `ServiceStats`.
 * `server`   — `python -m repro.serving.server`: a synthetic mixed
-  workload driver for smoke-testing a live service (the LM-side launch
-  driver `repro.launch.serve` is a different program; see docs).
+  workload driver for smoke-testing a live service.
 """
 from ..core.resilience import AdmissionError, TunerFailureWarning
 from .batcher import Batch, BatchKey, MicroBatcher, SolveRequest

@@ -10,10 +10,6 @@ JSON and exits non-zero if anything was dropped (CI's serving smoke job
 runs exactly this).  Every solved column is checked against the host
 reference oracle, so the run is a correctness gate, not just a liveness
 probe.
-
-This is the SOLVE service's driver.  `repro.launch.serve` is a
-different program — the LM-side prefill/decode launcher that CONSUMES
-triangular solves; see docs/serving.md for how the two relate.
 """
 from __future__ import annotations
 

@@ -158,8 +158,8 @@ def compose_sweep_fn(main_fn, schedule_dtype, pre_fn, reversed_: bool):
     `TriangularOperator.device_solve_fn` (production applications) and
     `Preconditioner._measure_pair` (measured pair tuning) build on it, so
     the tuner always times exactly the computation it selects for.
-    `pre_fn` is `solver.preamble.device_preamble`'s callable: one product
-    with B''s non-identity rows where the host plan exists, else the
+    `pre_fn` is `solver.preamble.SweepPreamble.device`'s callable: one
+    product with B''s non-identity rows where they fit its plan, else the
     T-factor schedule; None for identity preambles.  The preamble and the
     main schedule run under the name scopes `sptrsv.preamble` and
     `sptrsv.main`, which label their device ops in a profile
@@ -648,39 +648,18 @@ class TriangularOperator:
         # (core.faults.corrupt_values_payload) patches the schedule module
         from . import schedule as _schedule
         cfg = base["config"]
-        chunk = cfg.get("chunk", 256)
-        max_deps = cfg.get("max_deps", 16)
-        dtype = np.dtype(cfg.get("dtype", "float32"))
         L_eff, reversed_ = orient_lower(L_new, cfg.get("side", "lower"),
                                         bool(cfg.get("transpose", False)))
         ts_new = replay_transform(L_eff, base["ts"],
                                   where="TriangularOperator.update_values")
         sched_new = _schedule.repack_schedule_values(
             base["sched"], ts_new.A.data, ts_new.diag)
-        # the preamble schedule (solve with the T factor) is value-bound
-        # too; repack it from the base entry when its value plan survived
-        # renumbering.  If the base never materialized it, stay lazy — the
-        # operator's _preamble_host builds it from the NEW transform on
-        # first use, so the update itself never enters build_schedule.
+        # the preamble keeps its value-free parts and repacks or re-derives
+        # the value-bound ones (SweepPreamble.revalued)
         new_runtime: dict = {"compiled": {}}
-        # the host preamble's B' pattern is value-free: keep it, and let
-        # the first host preamble work out B' from the NEW T values
-        plan = base.get("_runtime", {}).get("preamble_plan")
-        if plan is not None:
-            new_runtime["preamble_plan"] = plan.revalued(ts_new)
-        entry = base.get("_runtime", {}).get("preamble_host")
-        if entry is not None:
-            psched = entry[0]
-            if psched is None:
-                new_runtime["preamble_host"] = entry
-            elif psched.value_plan is not None:
-                new_runtime["preamble_host"] = (
-                    _schedule.repack_schedule_values(
-                        psched, ts_new.T.data, np.ones(ts_new.T.n_rows)),
-                    entry[1], entry[2])
-            else:
-                new_runtime["preamble_host"] = _schedule.schedule_for_preamble(
-                    ts_new, chunk=chunk, max_deps=max_deps, dtype=dtype)
+        pre = base.get("_runtime", {}).get("sweep_preamble")
+        if pre is not None:
+            new_runtime["sweep_preamble"] = pre.revalued(ts_new)
         return {"version": CACHE_VERSION, "strategy": base["strategy"],
                 "ts": ts_new, "sched": sched_new,
                 "report": base.get("report"), "config": cfg,
@@ -966,91 +945,21 @@ class TriangularOperator:
         with _obs.span("engine.get"):
             return np.asarray(out)
 
-    def _preamble_rows(self) -> int:
-        """Rows of the T factor with entries: the rows the host preamble
-        eliminates (0 = identity preamble), counted once on the shared
-        payload."""
-        rows = self._runtime.get("preamble_rows")
-        if rows is None:
-            rows = self._runtime["preamble_rows"] = int(
-                np.count_nonzero(self._ts.T.row_nnz()))
-        return rows
-
-    def _preamble_plan(self):
-        """The payload's `HostPreamble` (core.transform.host_preamble,
-        bounded by nnz of the factor), worked out on the first host
-        preamble or device sweep and kept on the shared payload; a
-        refactorized payload starts from its base's (`_derive_payload`)."""
-        plan = self._runtime.get("preamble_plan")
-        if plan is None:
-            from ..core.transform import host_preamble
-            plan = self._runtime["preamble_plan"] = host_preamble(
-                self._ts, self._L.nnz)
-        return plan
-
-    def _preamble(self, v: np.ndarray) -> np.ndarray:
-        """c = B'v on the host by the payload's preamble plan; the
-        `engine.preamble` span names the realization.  No span for the
-        identity preamble."""
-        rows = self._preamble_rows()
-        if not rows:
-            return self._ts.preamble(v)
-        with _obs.span("engine.preamble", rows=rows) as sp:
-            plan = self._preamble_plan()
-            sp.set(realization=plan.realization, entries=plan.entries)
-            return plan(v)
-
-    def _preamble_host(self):
-        """(LevelSchedule|None, src, row_pos) for the T-factor preamble,
-        compiled once on the shared payload (None = identity preamble)."""
-        entry = self._runtime.get("preamble_host")
-        if entry is None:
-            from .schedule import schedule_for_preamble
-            entry = self._runtime["preamble_host"] = schedule_for_preamble(
-                self._ts, chunk=self._config.get("chunk", 256),
-                max_deps=self._config.get("max_deps", 16),
-                dtype=np.dtype(self._config.get("dtype", "float32")))
-        return entry
-
-    def _preamble_staged(self):
-        """_preamble_host with the schedule staged to device, once on the
-        shared payload (for engines that compile DeviceSchedules; host-
-        lowering engines take _preamble_host directly)."""
-        entry = self._runtime.get("preamble")
-        if entry is None:
-            import jax
-            from .levelset import to_device
-            psched, src, row_pos = self._preamble_host()
-            with jax.ensure_compile_time_eval():    # see _staged
-                entry = ((to_device(psched) if psched is not None else None),
-                         src, row_pos)
-            self._runtime["preamble"] = entry
-        return entry
-
-    def _device_preamble(self, engine):
-        """The sweep's device preamble for `engine`
-        (solver.preamble.device_preamble), built once per engine on the
-        shared payload: B''s rows as one product where the payload's
-        preamble plan exists, else the T-factor schedule, which is then
-        the only case that builds, stages and compiles it."""
-        from .engines import compile_source
-        from .preamble import device_preamble
-        built = self._runtime.setdefault("device_preamble", {})
-        cached = built.get(engine.name)
-        if cached is not None and cached[0] is engine:
-            return cached[1]
-
-        def scheduled():
-            psched, src, row_pos = self._preamble_host()
-            # same host-vs-staged branch as _compiled_fn
-            return engine.compile(compile_source(
-                engine, psched, lambda: self._preamble_staged()[0])), \
-                src, row_pos
-
-        pre_fn = device_preamble(self._preamble_plan(), self._canon_dtype(),
-                                 engine, scheduled)
-        built[engine.name] = (engine, pre_fn)
-        return pre_fn
+    @property
+    def preamble(self):
+        """The sweep's preamble c = B'v in its host and device forms
+        (solver.preamble.SweepPreamble, bounded by nnz of the factor), one
+        per payload: memory-cache hits share it, and a refactorized
+        payload starts from its base's (`_derive_payload`)."""
+        pre = self._runtime.get("sweep_preamble")
+        if pre is None:
+            from .preamble import SweepPreamble
+            cfg = self._config
+            pre = self._runtime.setdefault("sweep_preamble", SweepPreamble(
+                self._ts, self._L.nnz, chunk=cfg.get("chunk", 256),
+                max_deps=cfg.get("max_deps", 16),
+                dtype=np.dtype(cfg.get("dtype", "float32"))))
+        return pre
 
     def device_solve_fn(self, engine=None):
         """The operator's sweep as a pure JAX callable — jit/while_loop
@@ -1059,10 +968,10 @@ class TriangularOperator:
         Returns fn(v) -> x for v of shape (n,) or (n, k): axis reversal
         (transpose/upper sweeps), the transform's preamble c = B'v, and the
         main schedule all run on device in the schedule dtype; the result
-        is cast back to v's dtype.  The preamble is one product with B''s
-        non-identity rows, in the schedule dtype, where the payload's host
-        preamble plan exists (B' within nnz of the factor); only where it
-        does not is it the T factor's own level schedule
+        is cast back to v's dtype.  The preamble (`preamble.device`) is
+        one product with B''s non-identity rows, in the schedule dtype,
+        where they fit its plan (B' within nnz of the factor); only where
+        they do not is it the T factor's own level schedule
         (schedule_for_preamble), compiled through the SAME engine
         (solver/preamble.py).  No float64 iterative refinement — this is
         the raw device pipeline, which is exactly what preconditioner
@@ -1075,8 +984,9 @@ class TriangularOperator:
             raise ValueError(
                 "operator has no resolvable default engine "
                 f"({self._engine_name!r}); pass engine= explicitly")
-        return compose_sweep_fn(self._compiled_fn(eng), self._canon_dtype(),
-                                self._device_preamble(eng), self._reversed)
+        return compose_sweep_fn(
+            self._compiled_fn(eng), self._canon_dtype(),
+            self.preamble.device(eng, self._canon_dtype()), self._reversed)
 
     def _oriented_solve(self, v: np.ndarray, engine,
                         out_dtype=None) -> np.ndarray:
@@ -1088,7 +998,7 @@ class TriangularOperator:
         corrections accumulate at full precision."""
         if self._reversed:
             v = v[::-1]
-        x = self._device_solve(self._preamble(v), engine)
+        x = self._device_solve(self.preamble.host(v), engine)
         if out_dtype is not None:
             x = x.astype(out_dtype)
         return x[::-1] if self._reversed else x

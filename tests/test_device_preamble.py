@@ -1,19 +1,20 @@
 """The transform's preamble inside device sweeps (solver/preamble.py).
 
-`device_solve_fn` applies c = B'v as one product with B''s non-identity
-rows where the payload's host preamble plan exists, and as the T factor's
-own level schedule only where it does not.  Each case runs a transformed
-sweep's device pipeline in float32 against the sequential float64
-reference and reads which form the program's counters say was built.
+`device_solve_fn` applies c = B'v (the operator's `SweepPreamble`) as one
+product with B''s non-identity rows where they fit its plan, and as the T
+factor's own level schedule only where they do not.  Each case runs a
+transformed sweep's device pipeline in float32 against the sequential
+float64 reference and reads which form the program's counters say was
+built.
 """
 import numpy as np
 import pytest
 
-from repro.core.transform import host_preamble
 from repro.obs import default_registry
 from repro.precond import Preconditioner
 from repro.solver import TriangularOperator, orient_lower, solve_csr_seq
 from repro.solver import distributed as dist
+from repro.solver.preamble import SweepPreamble
 from repro.sparse import generators
 
 COUNTERS = ("sweep.device_preamble_spmv", "sweep.device_preamble_schedule",
@@ -40,6 +41,16 @@ def _reference(op, b: np.ndarray) -> np.ndarray:
     return x.reshape(b.shape)
 
 
+def _cap(op) -> None:
+    """Give `op` a preamble whose B' plan is capped to None (a cap of one
+    entry: B''s rows never fit), so its device form is the T-factor
+    schedule."""
+    cfg = op._config
+    op._runtime["sweep_preamble"] = SweepPreamble(
+        op.transformed, 1, chunk=cfg["chunk"], max_deps=cfg["max_deps"],
+        dtype=np.dtype(cfg["dtype"]))
+
+
 def _poisson_pair(**kw):
     A = generators.poisson2d_spd(32, 32)
     return A, Preconditioner.ic0(A, tune="avgLevelCost", cache=False, **kw)
@@ -60,7 +71,7 @@ def _case(name: str):
     if name == "refactor":
         A, P = _poisson_pair()
         P.device_apply()                    # built on the old values
-        old = P.forward._preamble_plan().B.data.copy()
+        old = P.forward.preamble.B.data.copy()
         A2 = type(A)(indptr=A.indptr, indices=A.indices,
                      data=np.where(A.indices == np.repeat(
                          np.arange(A.n_rows), np.diff(A.indptr)),
@@ -68,7 +79,7 @@ def _case(name: str):
         P.refactor(A2)
         op = P.forward
         # the new factor's B' values differ from the old ones
-        assert not np.allclose(op._preamble_plan().B.data, old)
+        assert not np.allclose(op.preamble.B.data, old)
         return op, "spmv"
     if name == "sharded":
         op = _poisson_pair(mesh=dist.default_mesh())[1].forward
@@ -76,8 +87,7 @@ def _case(name: str):
         return op, "spmv"
     assert name == "tfactor"
     op = _poisson_pair()[1].forward
-    # a cap of one entry: B''s rows never fit, the plan is None
-    op._runtime["preamble_plan"] = host_preamble(op.transformed, 1)
+    _cap(op)
     return op, "tfactor"
 
 
@@ -85,19 +95,23 @@ def _case(name: str):
 @pytest.mark.parametrize("name", ["poisson_forward", "poisson_backward",
                                   "lung2", "refactor", "sharded", "tfactor"])
 def test_device_preamble_matches_the_sequential_solve(name, k):
-    import jax.numpy as jnp
     op, realization = _case(name)
     assert not op.transformed.identity_preamble
     before = _totals()
     fn = op.device_solve_fn()
     spmv, scheduled, entries = (a - b for a, b in zip(_totals(), before))
-    plan = op._preamble_plan()
-    assert plan.realization == realization
+    pre = op.preamble
+    assert pre.realization == realization
     if realization == "spmv":
-        assert (spmv, scheduled, entries) == (1, 0, plan.entries)
-        assert "preamble_host" not in op._runtime
+        assert (spmv, scheduled, entries) == (1, 0, pre.entries)
+        assert "schedule" not in vars(pre)
     else:
         assert (spmv, scheduled, entries) == (0, 1, 0)
+    _assert_matches_reference(op, fn, k)
+
+
+def _assert_matches_reference(op, fn, k) -> None:
+    import jax.numpy as jnp
     shape = (op.n,) if k is None else (op.n, k)
     b = np.random.default_rng(11).standard_normal(shape)
     y = np.asarray(fn(jnp.asarray(b, jnp.float32)))
@@ -106,3 +120,76 @@ def test_device_preamble_matches_the_sequential_solve(name, k):
     # float32 sweeps of these well-conditioned factors: ~1e-7 relative
     assert np.abs(y - x).max() / np.abs(x).max() < 1e-5
 
+
+# ----------------------------------------------------------------------
+# every strategy the pair tuner may time, in every orientation
+
+STRATEGIES = ("no_rewriting", "avgLevelCost", "manual_every_k",
+              "constrained_avg", "critical_path")
+ORIENTATIONS = {"lower": ("lower", False), "lower_t": ("lower", True),
+                "upper": ("upper", False), "upper_t": ("upper", True)}
+_LUNG = generators.lung2_like(scale=0.02)
+
+
+@pytest.fixture(scope="module")
+def sweeps():
+    """(strategy, orientation, capped) -> (operator, its device sweep, the
+    counters' increments while that sweep was built), each built once."""
+    built = {}
+
+    def get(strategy, orientation, capped=False):
+        key = (strategy, orientation, capped)
+        if key not in built:
+            side, transpose = ORIENTATIONS[orientation]
+            M = _LUNG if side == "lower" else _LUNG.transpose()
+            op = TriangularOperator.from_csr(M, tune=strategy, side=side,
+                                             transpose=transpose, cache=False)
+            if capped:
+                _cap(op)
+            before = _totals()
+            fn = op.device_solve_fn()
+            built[key] = (op, fn, [a - b for a, b in zip(_totals(), before)])
+        return built[key]
+    return get
+
+
+def _assert_counted(op, counted) -> None:
+    """The counters name the form the sweep was built with; a second
+    build on the same engine is the cached one and counts nothing."""
+    pre = op.preamble
+    if op.transformed.identity_preamble:
+        assert pre.device(op._engine, np.float32) is None
+        expected = [0, 0, 0]
+    elif pre.realization == "spmv":
+        expected = [1, 0, pre.entries]
+    else:
+        expected = [0, 1, 0]
+    assert counted == expected
+    before = _totals()
+    op.device_solve_fn()
+    assert _totals() == before
+
+
+@pytest.mark.parametrize("k", [None, 3], ids=["vector", "block"])
+@pytest.mark.parametrize("orientation", list(ORIENTATIONS))
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_every_strategy_device_sweep_matches_the_sequential_solve(
+        sweeps, strategy, orientation, k):
+    op, fn, counted = sweeps(strategy, orientation)
+    assert op.transformed.identity_preamble == (strategy == "no_rewriting")
+    _assert_counted(op, counted)
+    _assert_matches_reference(op, fn, k)
+
+
+@pytest.mark.parametrize("orientation", ["lower", "lower_t"],
+                         ids=["forward", "reversed"])
+@pytest.mark.parametrize("strategy", STRATEGIES[1:])
+def test_capped_plan_runs_the_tfactor_schedule(sweeps, strategy,
+                                               orientation):
+    op, fn, counted = sweeps(strategy, orientation, capped=True)
+    assert op._reversed == (orientation == "lower_t")
+    assert op.transformed.T.nnz > 0
+    assert op.preamble.realization == "tfactor"
+    assert "schedule" in vars(op.preamble)
+    _assert_counted(op, counted)
+    _assert_matches_reference(op, fn, None)

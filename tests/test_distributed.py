@@ -118,9 +118,10 @@ def test_sharded_operator_never_stages_unpadded_schedules():
     assert np.abs(x - x_ref).max() / scale < 1e-3
     assert np.abs(y - x_ref).max() / scale < 1e-3
     assert op._runtime.get("dsched") is None
-    assert op._runtime.get("preamble") is None
-    assert op._preamble_plan().realization == "spmv"
-    assert "preamble_host" not in op._runtime
+    assert op.preamble.realization == "spmv"
+    # no T-factor schedule was built, so none was staged either
+    assert "schedule" not in vars(op.preamble)
+    assert "_staged" not in vars(op.preamble)
 
 
 def test_mesh_pair_decision_defaults_to_sharded_cost_model():

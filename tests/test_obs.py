@@ -616,7 +616,7 @@ def test_raw_solve_splits_engine_solve_into_its_parts(lung_op):
     assert _children(spans, eng) == SWEEP_SPANS
     by_name = {s.name: s for s in spans}
     rows = int(np.count_nonzero(op.transformed.T.row_nnz()))
-    entries = op._preamble_plan().entries
+    entries = op.preamble.entries
     assert by_name["engine.preamble"].attrs == {
         "rows": rows, "realization": "spmv", "entries": entries}
     assert rows > 0 and entries > 0
@@ -714,7 +714,7 @@ def test_device_sweep_scopes_preamble_and_main(lung_op):
     assert any("/sptrsv.main/" in n and "/sptrsv.step/" in n
                for n in names)
     # B' fits the plan: the preamble is one product, with no step
-    assert op._preamble_plan().realization == "spmv"
+    assert op.preamble.realization == "spmv"
     assert not any("/sptrsv.preamble/" in n and "/sptrsv.step/" in n
                    for n in names)
 
@@ -725,11 +725,11 @@ def test_scheduled_device_preamble_is_steps_under_its_scope(lung_op):
     `sptrsv.preamble`."""
     import jax
     import jax.numpy as jnp
-    from repro.core.transform import host_preamble
     from repro.solver import TriangularOperator
+    from repro.solver.preamble import SweepPreamble
     L, _ = lung_op
     op = TriangularOperator.from_csr(L, tune="avgLevelCost", cache=False)
-    op._runtime["preamble_plan"] = host_preamble(op.transformed, 1)
+    op._runtime["sweep_preamble"] = SweepPreamble(op.transformed, 1)
     text = jax.jit(op.device_solve_fn()).lower(
         jnp.zeros(L.n_rows, jnp.float32)).compile().as_text()
     assert any("/sptrsv.preamble/" in n and "/sptrsv.step/" in n

@@ -183,23 +183,24 @@ def test_measured_mode_agrees_with_cost_ordering():
 
 def test_measure_times_the_operators_host_preamble(monkeypatch, lung_small):
     """The measured wall time runs the preamble the operator would run:
-    `host_preamble` bounded by the factor's nnz, not the T-factor loop."""
-    import repro.core.portfolio as pf
+    `SweepPreamble.host` bounded by the factor's nnz, not the T-factor
+    loop."""
     from repro.core.transform import TransformedSystem
+    from repro.solver.preamble import SweepPreamble
     seen = []
 
-    def spy(ts, max_entries):
-        pre = real(ts, max_entries)
-        seen.append((pre.realization, max_entries))
-        return pre
+    def spy(self, v):
+        seen.append((self.realization, self.max_entries))
+        return real(self, v)
 
     def no_loop(self, b):
         raise AssertionError("the tuner ran the T-factor loop")
 
-    real = pf.host_preamble
-    monkeypatch.setattr(pf, "host_preamble", spy)
+    real = SweepPreamble.host
+    monkeypatch.setattr(SweepPreamble, "host", spy)
     monkeypatch.setattr(TransformedSystem, "preamble", no_loop)
     rep = StrategyPortfolio(candidates=[AvgLevelCost()], measure_top_k=1,
                             measure_iters=2).tune(lung_small)
-    assert seen == [("spmv", lung_small.nnz)]
+    # one warm-up call and one per timed rep, all by the one plan
+    assert len(seen) >= 3 and set(seen) == {("spmv", lung_small.nnz)}
     assert rep.best.measured_us is not None and rep.best.measure_note is None

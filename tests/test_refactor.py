@@ -164,24 +164,24 @@ def test_update_values_rebuilds_preamble_plan(rhs, monkeypatch):
     L, L2 = _lower(), _revalued(_lower(), seed=4)
     op = TriangularOperator.from_csr(L, "avgLevelCost", cache=False)
     op.solve(rhs, max_refine=0)
-    old = op._runtime["preamble_plan"]
+    old = op.preamble
     assert old.realization == "spmv"
     old_B = old.B
     op.update_values(L2)
     _no_pattern_pass(monkeypatch)
-    new = op._runtime["preamble_plan"]
+    new = op.preamble
     assert new is not old and new.pattern is old.pattern
     assert new.ts is op.transformed and "B" not in vars(new)
     x = op.solve(rhs, max_refine=0)
     assert "B" in vars(new) and np.array_equal(new.B.indices, old_B.indices)
     assert not np.array_equal(new.B.data, old_B.data)
     c_ref = op.transformed.preamble(rhs)
-    assert np.abs(op._preamble(rhs) - c_ref).max() \
+    assert np.abs(new.host(rhs) - c_ref).max() \
         <= 1e-13 * np.abs(c_ref).max()
     monkeypatch.undo()
     fresh = TriangularOperator.from_csr(L2, "avgLevelCost", cache=False)
     assert np.array_equal(x, fresh.solve(rhs, max_refine=0))
-    assert np.array_equal(new.B.data, fresh._preamble_plan().B.data)
+    assert np.array_equal(new.B.data, fresh.preamble.B.data)
 
 
 def test_update_values_keeps_the_tfactor_decision(monkeypatch):
@@ -192,10 +192,10 @@ def test_update_values_keeps_the_tfactor_decision(monkeypatch):
     b = np.random.default_rng(6).standard_normal(L.n_rows)
     op = TriangularOperator.from_csr(L, "avgLevelCost", cache=False)
     op.solve(b, max_refine=0)
-    assert op._preamble_plan().realization == "tfactor"
+    assert op.preamble.realization == "tfactor"
     op.update_values(L2)
     _no_pattern_pass(monkeypatch)
-    assert op._runtime["preamble_plan"].realization == "tfactor"
+    assert op.preamble.realization == "tfactor"
     x = op.solve(b, max_refine=0)
     monkeypatch.undo()
     fresh = TriangularOperator.from_csr(L2, "avgLevelCost", cache=False)

@@ -139,7 +139,7 @@ _LUNG = generators.lung2_like(scale=0.05)
 
 def _assert_same_preamble(op, b):
     c_ref = op.transformed.preamble(b)
-    c = op._preamble(b)
+    c = op.preamble.host(b)
     assert c.dtype == c_ref.dtype == np.float64
     assert c.shape == c_ref.shape == b.shape
     assert np.abs(c - c_ref).max() <= 1e-13 * np.abs(c_ref).max()
@@ -156,7 +156,7 @@ def test_preamble_plan_matches_tfactor(side, transpose, dtype, k):
     (whose right-hand side reaches the preamble as a reversed view)."""
     op = _plan_op(_LUNG, side, transpose)
     assert not op.transformed.identity_preamble
-    plan = op._preamble_plan()
+    plan = op.preamble
     assert plan.realization == "spmv"
     assert plan.entries == plan.B.nnz <= _LUNG.nnz
     # only original rows, and only those the T factor touches
@@ -172,7 +172,7 @@ def test_preamble_plan_falls_back_when_b_fills_in():
     T-factor loop, and gives the same answer."""
     L = generators.poisson2d_ic0(16, 16)
     op = _plan_op(L)
-    plan = op._preamble_plan()
+    plan = op.preamble
     assert (plan.realization, plan.entries, plan.pattern) == \
         ("tfactor", op.transformed.T.nnz, None)
     for b in (np.random.default_rng(6).standard_normal(op.n),
@@ -183,11 +183,11 @@ def test_preamble_plan_falls_back_when_b_fills_in():
 
 
 def test_preamble_plan_bound_is_the_factors_nnz():
-    from repro.core.transform import host_preamble
+    from repro.solver.preamble import SweepPreamble
     ts = _plan_op(_LUNG).transformed
-    entries = host_preamble(ts, _LUNG.nnz).entries
-    assert host_preamble(ts, entries).realization == "spmv"
-    assert host_preamble(ts, entries - 1).realization == "tfactor"
+    entries = SweepPreamble(ts, _LUNG.nnz).entries
+    assert SweepPreamble(ts, entries).realization == "spmv"
+    assert SweepPreamble(ts, entries - 1).realization == "tfactor"
 
 
 def test_preamble_plan_rows_match_materialized_b():
@@ -219,9 +219,9 @@ def test_identity_preamble_has_no_plan_and_no_span():
     b = np.random.default_rng(9).standard_normal(op.n).astype(np.float32)
     tr = obs.enable()
     try:
-        c = op._preamble(b)
+        c = op.preamble.host(b)
     finally:
         obs.disable()
     assert c.dtype == np.float64 and np.array_equal(c, b)
     assert not any(s.name == "engine.preamble" for s in tr.spans())
-    assert "preamble_plan" not in op._runtime
+    assert "pattern" not in vars(op.preamble)
